@@ -66,16 +66,17 @@ from __future__ import annotations
 
 import gc
 import json
-import os
 import statistics
 import sys
 import time
 from pathlib import Path
 
+import jax
+
 # device_first_call_s must stay an honest per-process compile cost:
 # a warm persistent compilation cache would report disk-replay time
-# instead (an explicit env value still wins)
-os.environ.setdefault("REPRO_JAX_CACHE_DIR", "off")
+# instead
+jax.config.update("jax_enable_compilation_cache", False)
 
 # the committed/CI baseline is the smoke grid (by design: ~1k scenarios
 # in seconds); a full-scale run writes its own file so it never

@@ -16,6 +16,7 @@ from typing import Dict, Optional
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.host import on_host
 from repro.core.microgrid import MicrogridConfig, simulate, summarize
 from repro.core.power import PowerModel
 from repro.core.signals import Signal, aggregate_power
@@ -60,10 +61,11 @@ def run_cosim(load: Signal, solar: Signal, ci: Signal,
     cfg = cfg or MicrogridConfig()
     # align all signals on the load grid
     t = load.times
-    lw = jnp.asarray(load.values)
-    sw = jnp.asarray(solar.at(t))
-    cw = jnp.asarray(ci.at(t))
-    tr = simulate(lw, sw, cw, cfg)
+    with on_host():
+        lw = jnp.asarray(load.values)
+        sw = jnp.asarray(solar.at(t))
+        cw = jnp.asarray(ci.at(t))
+        tr = simulate(lw, sw, cw, cfg)
     tr_np = {k: np.asarray(v) for k, v in tr.items()}
     metrics = summarize(np.asarray(lw), np.asarray(sw), np.asarray(cw),
                         tr_np, cfg)

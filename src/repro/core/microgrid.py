@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.host import on_host
+
 
 @dataclasses.dataclass(frozen=True)
 class BatteryConfig:
@@ -80,8 +82,9 @@ def simulate(load_w: jnp.ndarray, solar_w: jnp.ndarray, ci: jnp.ndarray,
         }
         return new_soc, out
 
-    soc0 = jnp.asarray(b.soc_init * b.capacity_wh)
-    _, tr = jax.lax.scan(step, soc0, (load_w, solar_w, ci))
+    with on_host():
+        soc0 = jnp.asarray(b.soc_init * b.capacity_wh)
+        _, tr = jax.lax.scan(step, soc0, (load_w, solar_w, ci))
     return tr
 
 

@@ -11,7 +11,7 @@ TPU profiles are our hardware adaptation (documented estimates from
 public TDP / efficiency figures; same functional form).
 
 All functions are vectorized jnp so whole MFU traces (and vmapped
-scenario sweeps) evaluate in one call.
+scenario sweeps) evaluate in one call, on the host CPU (``core.host``).
 """
 from __future__ import annotations
 
@@ -19,6 +19,8 @@ import dataclasses
 from typing import Dict
 
 import jax.numpy as jnp
+
+from repro.core.host import on_host
 
 
 @dataclasses.dataclass(frozen=True)
@@ -73,9 +75,11 @@ DEVICES["v5p"] = TPU_V5P
 
 def power(mfu, dev: DeviceProfile):
     """Eq. 1, vectorized. mfu in [0, 1] (fraction, not percent)."""
-    mfu = jnp.clip(jnp.asarray(mfu, jnp.float32), 0.0, None)
-    x = jnp.minimum(mfu, dev.mfu_sat) / dev.mfu_sat
-    return dev.p_idle + (dev.p_max_inst - dev.p_idle) * jnp.power(x, dev.gamma)
+    with on_host():
+        mfu = jnp.clip(jnp.asarray(mfu, jnp.float32), 0.0, None)
+        x = jnp.minimum(mfu, dev.mfu_sat) / dev.mfu_sat
+        return dev.p_idle + (dev.p_max_inst - dev.p_idle) * jnp.power(
+            x, dev.gamma)
 
 
 class PowerModel:
@@ -90,4 +94,6 @@ class PowerModel:
     def energy_wh(self, mfu, duration_s, n_devices: int = 1, pue: float = 1.0):
         """Energy in Wh for stages with given MFU and duration (Eq. 3)."""
         p = self.power(mfu)
-        return jnp.sum(p * jnp.asarray(duration_s) / 3600.0) * n_devices * pue
+        with on_host():
+            return (jnp.sum(p * jnp.asarray(duration_s) / 3600.0)
+                    * n_devices * pue)

@@ -17,8 +17,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 
 def pipeline_forward(stage_fn: Callable, n_stages: int, n_micro: int,
@@ -67,12 +66,13 @@ def pipeline_forward(stage_fn: Callable, n_stages: int, n_micro: int,
         # only the last stage holds nonzero outputs; psum broadcasts them
         return jax.lax.psum(outputs, stage_axis)
 
-    return shard_map(
+    return jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P(stage_axis), P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
 
 
 def make_pp_mesh(n_stages: int, n_data: int = 1):
-    return jax.make_mesh((n_stages, n_data), ("stage", "data"))
+    return jax.make_mesh((n_stages, n_data), ("stage", "data"),
+                         axis_types=(AxisType.Auto,) * 2)

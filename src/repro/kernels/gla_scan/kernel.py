@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 
 def _gla_kernel(q_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_out_ref, s_scr,
@@ -119,7 +118,7 @@ def gla_scan_pallas(q, k, v, log_w, u: Optional[jnp.ndarray] = None,
             jax.ShapeDtypeStruct((B, H, K, V), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, log_w, u)

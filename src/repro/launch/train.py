@@ -20,6 +20,7 @@ from repro.configs import get_config, reduced_config
 from repro.configs.base import ShapeConfig
 from repro.distributed import axes as axlib
 from repro.distributed.sharding import batch_pspecs, make_plan, param_pspecs
+from repro.launch.mesh import auto_mesh
 from repro.models import build_model
 from repro.train.data import DataConfig, SyntheticLM
 from repro.train.fault_tolerance import FaultToleranceConfig, FaultTolerantRunner
@@ -45,7 +46,7 @@ def main():
     if args.reduced:
         cfg = reduced_config(cfg)
     d, m = (int(x) for x in args.mesh.split("x"))
-    mesh = jax.make_mesh((d, m), ("data", "model"))
+    mesh = auto_mesh((d, m), ("data", "model"))
     shape = ShapeConfig("cli", args.seq, args.batch, "train")
     plan = make_plan(cfg, mesh, "train", shape, variant=args.variant)
     c = plan.cfg

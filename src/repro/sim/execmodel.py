@@ -37,6 +37,7 @@ from typing import Optional, Sequence
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.core.host import on_host
 from repro.core.power import DEVICES, DeviceProfile
 
 
@@ -321,8 +322,8 @@ class ExecutionModel:
 
         ``backend="numpy"`` (default) is the reference path — bit-
         identical to the scalar ``stage_cost``. ``backend="jax"`` jits
-        the same kernel (float32 on most platforms, so outputs are
-        close but not bit-equal; use it for throughput, not pinning).
+        the same kernel on the host CPU (float32, so outputs are close
+        but not bit-equal; ``JAX_BACKEND_RTOL`` bounds them).
         """
         args = (np.asarray(batch.prefill_tokens, np.float64),
                 np.asarray(batch.decode_count, np.float64),
@@ -338,7 +339,8 @@ class ExecutionModel:
                 self._jax_kernel = jax.jit(
                     lambda npt, nd, sc, kv: _roofline(npt, nd, sc, kv,
                                                       p, jnp))
-            out = self._jax_kernel(*args)
+            with on_host():
+                out = self._jax_kernel(*args)
             return StageCostBatch(*(np.asarray(v) for v in out))
         raise ValueError(f"unknown backend {backend!r}")
 

@@ -19,22 +19,25 @@ for groups the conservative predicate rejects. Record assembly reuses
 the event-loop columns.
 
 **Tolerance contract** (see README): numpy modes are bit-identical to
-the event loop; device mode is NOT — the roofline and the Eq. 2-4
-arithmetic are elementwise float64 (identical IEEE results under XLA),
-but (a) the trace-level reductions (``sum(P_i*dt_i)``, ``sum(dt_i)``,
-``sum(MFU_i*dt_i)``) reassociate — jnp's tree reduction vs numpy's
-pairwise summation, ~1e-14 relative — and (b) the Eq. 1 power curve
-is evaluated in float32 (mirroring ``core.power.power``) where XLA's
-fused ``pow`` may differ from the eager op by a few f32 ulps, ~1e-7
-relative on the power factor. ``DEVICE_MODE_RTOL`` bounds both with
-margin; columns that never pass through the device program (latency
+the event loop; device mode is NOT. (a) The roofline and the Eq. 2-4
+arithmetic run in float64, which a TPU emulates: ~1e-14 relative per
+operation and float32's exponent range, so duration and embodied
+carbon land ~1e-14 off, as do the reassociated trace reductions
+(``sum(P_i*dt_i)``, ``sum(dt_i)``, ``sum(MFU_i*dt_i)``). (b) The Eq. 1
+power curve is evaluated in float32, mirroring ``core.power.power``;
+XLA:CPU's ``pow`` agrees with the host's to an ulp (~1e-7 on the
+records), a TPU v5e's strays up to 67 ulps (~5e-6 on the factor,
+1.7e-6 on the records of the paper grids). ``DEVICE_MODE_RTOL`` bounds
+both; columns that never pass through the device program (latency
 percentiles, throughput, MFU/batch averages, stage counts) come from
 the host-side trace and stay bitwise.
 """
 from __future__ import annotations
 
 import dataclasses
+import os
 import time
+from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -52,10 +55,21 @@ from repro.sweep.vectorized import group_by_trace
 
 #: documented ulp-level equivalence bound for device-mode records
 #: against event-loop records (relative, per metric column) — the f32
-#: Eq. 1 power evaluation dominates (~1e-7); 5e-6 leaves >10x margin
-#: while still catching any real logic divergence. CI pins the perf
-#: grid under this bound (benchmarks/perf_sweep.py --check-device).
+#: Eq. 1 power evaluation dominates: ~1e-7 on the CPU, 1.7e-6 on a TPU
+#: v5e (its f32 pow), so 5e-6 leaves 3x margin there while still
+#: catching any real logic divergence. CI pins the perf grid under
+#: this bound on the CPU (benchmarks/perf_sweep.py --check-device);
+#: chip_smoke.py pins every paper grid on the chip.
 DEVICE_MODE_RTOL = 5e-6
+
+#: record columns the device program computes (f32 Eq. 1 power and
+#: reassociated reductions, so held to ``DEVICE_MODE_RTOL``); every
+#: other column comes from the host-side trace and is bitwise
+DEVICE_COLS = frozenset({
+    "energy_wh", "energy_kwh", "avg_power_w", "peak_power_w",
+    "duration_s", "gpu_hours", "carbon_operational_g",
+    "carbon_embodied_g", "carbon_total_g",
+})
 
 
 @dataclasses.dataclass
@@ -65,6 +79,9 @@ class DeviceStats:
     event_loops: int = 0     # groups driven through the event loop
     replayed: int = 0        # groups served by divergence replay
     devices: int = 1         # accelerators the dispatch sharded over
+    platform: str = ""       # jax platform the program ran on ("tpu")
+    device_kind: str = ""    # e.g. "TPU v5 lite"; "cpu" on the host
+    bucket: Tuple[int, int, int] = (0, 0, 0)   # padded (G, S, K)
 
 
 def _next_pow2(n: int) -> int:
@@ -115,49 +132,42 @@ _PMAP_PROGRAMS: Dict[int, object] = {}
 # jit cache — the wall-clock profiler labels the two differently
 _SEEN_SHAPES: set = set()
 
-#: persistent-compilation-cache location; "off"/"0"/"none"/"" disables
-#: (tests that pin compile-vs-execute span names set it off so a warm
-#: on-disk cache can't blur the distinction)
-ENV_JAX_CACHE_DIR = "REPRO_JAX_CACHE_DIR"
-DEFAULT_JAX_CACHE_DIR = "results/jax_cache"
+#: persistent compilation cache when ``$JAX_COMPILATION_CACHE_DIR`` is
+#: unset: anchored on the checkout, not the cwd, because the directory
+#: is part of every entry's key and a moving path never hits
+DEFAULT_JAX_CACHE_DIR = (Path(__file__).resolve().parents[3]
+                         / "results" / "jax_cache")
 
-_PERSIST_CONFIGURED = False
+_CACHE_CONFIGURED = False
 
 
-def _maybe_persistent_cache() -> None:
-    """Point jax at an on-disk compilation cache so the device
-    program's XLA compile (``device_first_call_s``, ~0.3s/process) is
-    paid once per shape bucket per machine instead of once per
-    process — exactly the cost profile remote workers and process
-    pools hit. Config keys are set best-effort: absent on older jax
-    versions just means no persistence."""
-    global _PERSIST_CONFIGURED
-    if _PERSIST_CONFIGURED:
+def _configure_compile_cache() -> None:
+    """Keep the device program's XLA compile on disk so it is paid once
+    per shape bucket per machine instead of once per process. JAX reads
+    ``$JAX_COMPILATION_CACHE_DIR`` itself; only without it does the
+    cache go to ``DEFAULT_JAX_CACHE_DIR``. A caller that needs a cold
+    compile turns the cache off with
+    ``jax.config.update("jax_enable_compilation_cache", False)``."""
+    global _CACHE_CONFIGURED
+    if _CACHE_CONFIGURED:
         return
-    _PERSIST_CONFIGURED = True
-    import os
-    raw = os.environ.get(ENV_JAX_CACHE_DIR, DEFAULT_JAX_CACHE_DIR)
-    if raw.strip().lower() in ("", "off", "0", "none"):
-        return
+    _CACHE_CONFIGURED = True
     import jax
-    try:
-        os.makedirs(raw, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", raw)
-        # the grid program compiles in ~0.3s — below the default 1s
-        # persistence threshold — so lower both floors to "always"
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                          -1)
-    except (AttributeError, OSError):
-        pass
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        DEFAULT_JAX_CACHE_DIR.mkdir(parents=True, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir",
+                          str(DEFAULT_JAX_CACHE_DIR))
+    # the grid program compiles in well under the default 1s
+    # persistence threshold, so lower both floors to "always"
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 
 def _program():
     global _PROGRAM
     if _PROGRAM is None:
         import jax
-        _maybe_persistent_cache()
+        _configure_compile_cache()
         _PROGRAM = jax.jit(jax.vmap(_group_kernel))
     return _PROGRAM
 
@@ -170,7 +180,7 @@ def _pmap_program(n_dev: int):
     prog = _PMAP_PROGRAMS.get(n_dev)
     if prog is None:
         import jax
-        _maybe_persistent_cache()
+        _configure_compile_cache()
         prog = jax.pmap(jax.vmap(_group_kernel))
         _PMAP_PROGRAMS[n_dev] = prog
     return prog
@@ -207,11 +217,13 @@ def _acquire_results(scenarios: Sequence[Scenario],
     return results, sim_elapsed
 
 
-def execute_device_grid(scenarios: Sequence[Scenario]
+def execute_device_grid(scenarios: Sequence[Scenario],
+                        max_devices: Optional[int] = None
                         ) -> Tuple[List[dict], DeviceStats]:
     """Execute a whole cache-missed grid: fleet scenarios pass through
     their own rollup; every single-site trace group is padded into one
-    batched tensor set and evaluated by a single device program."""
+    batched tensor set and evaluated by a single device program, on the
+    default backend's local devices (at most ``max_devices`` of them)."""
     import jax
 
     from repro.sweep.runner import (_execute_fleet_scenario,
@@ -279,7 +291,8 @@ def execute_device_grid(scenarios: Sequence[Scenario]
     # same process rely on f32 defaults). With >1 local accelerator the
     # padded group axis shards (D, G/D) across devices via pmap —
     # always exact: gp is a power of two, and so is D
-    n_local = jax.local_device_count()
+    local = jax.local_devices()
+    n_local = min(len(local), max_devices or len(local))
     d = 1
     while d * 2 <= min(n_local, gp):
         d *= 2
@@ -289,7 +302,7 @@ def execute_device_grid(scenarios: Sequence[Scenario]
     dispatch_span = ("device.jit_compile_and_execute"
                      if shape_sig not in _SEEN_SHAPES
                      else "device.execute")
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         with PROFILER.span(dispatch_span):
             if d > 1:
                 sharded = tuple(
@@ -304,6 +317,9 @@ def execute_device_grid(scenarios: Sequence[Scenario]
                     np.asarray(o) for o in out)
     _SEEN_SHAPES.add(shape_sig)
     stats.devices = d
+    stats.platform = local[0].platform
+    stats.device_kind = local[0].device_kind
+    stats.bucket = (gp, sp, kp)
 
     # ---- record assembly through the shared single-site path ----
     for gi, (g, res) in enumerate(zip(single, results)):

@@ -281,7 +281,7 @@ def reclaim_expired(job_dir: Path, lease_s: float, max_attempts: int
 # --------------------------------------------------------------------------
 
 def spawn_worker(queue_dir: Path, worker_id: Optional[str] = None,
-                 mode: str = "inherit", poll_s: float = 0.05,
+                 mode: str = "vectorized", poll_s: float = 0.05,
                  env: Optional[Dict[str, str]] = None,
                  log_path: Optional[Path] = None) -> subprocess.Popen:
     """Start a detached ``python -m repro.sweep.worker`` on this host.
@@ -294,7 +294,6 @@ def spawn_worker(queue_dir: Path, worker_id: Optional[str] = None,
     full_env.update(env or {})
     full_env["PYTHONPATH"] = pkg_root + os.pathsep + \
         full_env.get("PYTHONPATH", "")
-    full_env.setdefault("JAX_PLATFORMS", "cpu")
     cmd = [sys.executable, "-m", "repro.sweep.worker", str(queue_dir),
            "--mode", mode, "--poll-s", str(poll_s)]
     if worker_id:
@@ -399,10 +398,14 @@ class RemoteCoordinator:
                ) -> List[subprocess.Popen]:
         procs = []
         envs = list(self.opts.worker_env or [])
+        # a vectorized worker starts CPU-only; only device-mode workers
+        # may open the accelerator (one per chip)
+        mode = (self.mode if self.opts.worker_mode == "inherit"
+                else self.opts.worker_mode)
         for i in range(self.opts.spawn_workers):
             extra = envs[i] if i < len(envs) else {}
             procs.append(spawn_worker(
-                queue, worker_id=f"w{i}", mode=self.opts.worker_mode,
+                queue, worker_id=f"w{i}", mode=mode,
                 poll_s=self.opts.poll_s, env=extra,
                 log_path=job_dir / f"worker-w{i}.log"))
         return procs

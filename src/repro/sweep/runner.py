@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import dataclasses
 import multiprocessing
+import os
 import time
 from concurrent.futures import ProcessPoolExecutor
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -269,6 +270,11 @@ class SweepStats:
     trace_groups: int = 0     # unique simulation traces actually driven
     event_loops: int = 0      # device mode: groups run through the loop
     replayed: int = 0         # device mode: groups shared via divergence
+    # device mode: what the grid program ran on, so a run on the host
+    # CPU never passes for one on the chip
+    devices: int = 0
+    device_platform: str = ""
+    device_kind: str = ""
     # ResultCache effectiveness over this run (lookup-phase deltas);
     # cache_attached distinguishes a no-cache run from an all-miss one
     cache_attached: bool = False
@@ -291,6 +297,9 @@ class SweepStats:
         shared = (f" ({self.event_loops} event loop(s), "
                   f"{self.replayed} replayed)"
                   if self.mode == "device" and self.executed else "")
+        if self.device_platform:
+            shared += (f", on {self.devices}x {self.device_platform} "
+                       f"({self.device_kind})")
         eff = (f", cache {self.cache_memo} memo / {self.cache_disk} disk"
                f" / {self.cache_miss} miss"
                if self.cache_attached else "")
@@ -335,8 +344,8 @@ class SweepRunner:
     matches the numpy modes within ``device.DEVICE_MODE_RTOL`` (pinned
     by tests/test_device_mode.py).
 
-    ``workers > 1`` uses a spawn-context process pool (fork is unsafe
-    once jax has started its threadpools). ``cache=None`` disables
+    ``workers > 1`` uses a spawn-context process pool (``host_pool``)
+    whose children start JAX on the CPU alone. ``cache=None`` disables
     memoization entirely.
 
     ``probe`` attaches a ``repro.obs.Probe`` to every *executed*
@@ -467,12 +476,9 @@ class SweepRunner:
 
     def _run_event_loop(self, todo: List[Scenario], note) -> List[dict]:
         if self.probe is None and self.workers > 1 and len(todo) > 1:
-            ctx = multiprocessing.get_context("spawn")
             n = min(self.workers, len(todo))
             note(f"executing {len(todo)} scenarios on {n} processes")
-            with PROFILER.span("pool.event_loop"), \
-                    ProcessPoolExecutor(max_workers=n,
-                                        mp_context=ctx) as pool:
+            with PROFILER.span("pool.event_loop"), host_pool(n) as pool:
                 if PROFILER.enabled:
                     outs = list(pool.map(_execute_scenario_profiled, todo))
                     for _, agg in outs:
@@ -492,7 +498,6 @@ class SweepRunner:
         group_scs = [[todo[j] for j in g] for g in groups]
         if self.probe is None and self.workers > 1 and len(group_scs) > 1:
             from repro.sweep.vectorized import estimate_group_cost
-            ctx = multiprocessing.get_context("spawn")
             n = min(self.workers, len(group_scs))
             note(f"executing {len(todo)} scenarios as {len(groups)} "
                  f"trace group(s) on {n} processes")
@@ -504,9 +509,7 @@ class SweepRunner:
                            key=lambda i: (-estimate_group_cost(
                                group_scs[i]), i))
             ordered = [group_scs[i] for i in order]
-            with PROFILER.span("pool.vectorized"), \
-                    ProcessPoolExecutor(max_workers=n,
-                                        mp_context=ctx) as pool:
+            with PROFILER.span("pool.vectorized"), host_pool(n) as pool:
                 if PROFILER.enabled:
                     outs = list(pool.map(execute_scenario_group_profiled,
                                          ordered, chunksize=1))
@@ -555,7 +558,30 @@ class SweepRunner:
         stats.trace_groups = dstats.trace_groups
         stats.event_loops = dstats.event_loops
         stats.replayed = dstats.replayed
+        stats.devices = dstats.devices
+        stats.device_platform = dstats.platform
+        stats.device_kind = dstats.device_kind
         return fresh
+
+
+def use_cpu_only() -> None:
+    """Start this process's JAX with the CPU platform alone, for a
+    process that computes the host path only (pool children, vectorized
+    remote workers). Creating a backend opens every platform JAX may
+    use, so pinning a device later would still take the chip from the
+    process that holds it."""
+    import jax
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax.config.update("jax_platforms", "cpu")
+
+
+def host_pool(n: int) -> ProcessPoolExecutor:
+    """Spawn-context process pool for the host paths (fork is unsafe
+    once jax has started its threadpools); its children never open the
+    accelerator."""
+    return ProcessPoolExecutor(
+        max_workers=n, mp_context=multiprocessing.get_context("spawn"),
+        initializer=use_cpu_only)
 
 
 def _execute_scenario_profiled(sc: Scenario) -> Tuple[dict, dict]:

@@ -57,6 +57,19 @@ def choose_mode(worker_mode: str, payload: dict) -> str:
     return worker_mode
 
 
+def require_device_backend() -> None:
+    """A device-mode worker runs the grid program on an accelerator, or
+    on the CPU only where ``JAX_PLATFORMS`` names it: JAX's silent
+    fallback to the CPU would pass a host run off as a device run."""
+    import jax
+    if jax.default_backend() == "cpu" and "cpu" not in \
+            os.environ.get("JAX_PLATFORMS", "").split(","):
+        raise RuntimeError(
+            "device mode found no accelerator (JAX's default backend is "
+            "the CPU); set JAX_PLATFORMS=cpu to run the grid program on "
+            "the CPU on purpose")
+
+
 def execute_shard(payload: dict, cache: ResultCache, mode: str,
                   crash_after: Optional[int] = None) -> int:
     """Evaluate one shard's trace groups and persist every record into
@@ -69,6 +82,7 @@ def execute_shard(payload: dict, cache: ResultCache, mode: str,
     done_groups = 0
     if mode == "device":
         from repro.sweep.device import execute_device_grid
+        require_device_backend()
         flat = [sc for group in payload["groups"] for sc in group]
         with PROFILER.span("worker.device_grid"):
             records, _ = execute_device_grid(flat)
@@ -207,6 +221,16 @@ def main(argv=None) -> int:
     crash_after = args.crash_after_groups
     if crash_after is None and os.environ.get(remote.ENV_CRASH_AFTER_GROUPS):
         crash_after = int(os.environ[remote.ENV_CRASH_AFTER_GROUPS])
+
+    if args.mode == "vectorized":
+        from repro.sweep.runner import use_cpu_only
+        use_cpu_only()
+    elif args.mode == "device":
+        try:
+            require_device_backend()
+        except RuntimeError as exc:
+            print(f"[worker] {exc}", flush=True)
+            return 2
 
     # warm the execution stack BEFORE registering as alive, so
     # wait_for_workers() measures resident-cluster dispatch, not

@@ -16,6 +16,7 @@ bit-for-bit.
 import dataclasses
 import hashlib
 import json
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -30,19 +31,10 @@ from repro.sim.execmodel import (JAX_BACKEND_RTOL, ExecutionModel,
 from repro.sim.trace import StageTrace
 from repro.sweep import SCHEMA_VERSION, SWEEPS, SweepRunner
 from repro.sweep import divergence
-from repro.sweep.device import (DEVICE_MODE_RTOL, execute_device_grid,
-                                records_max_rel_err)
+from repro.sweep.device import (DEVICE_COLS, DEVICE_MODE_RTOL,
+                                execute_device_grid, records_max_rel_err)
 from repro.sweep.grid import Scenario
 from repro.sweep.runner import execute_scenario
-
-# columns the device program computes on-accelerator (f32 Eq.1 power +
-# reassociated reductions -> rtol-bounded); everything else is
-# host-side and must stay bit-identical to the event loop
-DEVICE_COLS = frozenset({
-    "energy_wh", "energy_kwh", "avg_power_w", "peak_power_w",
-    "duration_s", "gpu_hours", "carbon_operational_g",
-    "carbon_embodied_g", "carbon_total_g",
-})
 
 
 def _assert_device_contract(ev, dv):
@@ -301,6 +293,7 @@ def test_jax_backend_parity_all_paper_models(name):
 _SHARDED_DISPATCH_SCRIPT = """
 import json, os
 import jax
+jax.config.update("jax_enable_compilation_cache", False)
 assert jax.local_device_count() == 2, jax.local_device_count()
 from repro.sweep import SWEEPS, SweepRunner
 from repro.sweep.device import (DEVICE_MODE_RTOL, execute_device_grid,
@@ -326,8 +319,7 @@ def test_sharded_dispatch_across_two_host_devices():
 
     env = dict(os.environ)
     env.update({"JAX_PLATFORMS": "cpu",
-                "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
-                "REPRO_JAX_CACHE_DIR": "off"})
+                "XLA_FLAGS": "--xla_force_host_platform_device_count=2"})
     out = subprocess.run([sys.executable, "-c", _SHARDED_DISPATCH_SCRIPT],
                          env=env, capture_output=True, text=True)
     assert out.returncode == 0, out.stderr
@@ -336,42 +328,62 @@ def test_sharded_dispatch_across_two_host_devices():
     assert res["err"] <= res["rtol"]
 
 
-_PERSIST_CACHE_SCRIPT = """
+_ENV_CACHE_SCRIPT = """
 import os, sys
+import jax
 from repro.sweep import SWEEPS
 from repro.sweep.device import execute_device_grid
 execute_device_grid(SWEEPS["fig4"].build(True))
-root = os.environ["REPRO_JAX_CACHE_DIR"]
+root = os.environ["JAX_COMPILATION_CACHE_DIR"]
+assert jax.config.jax_compilation_cache_dir == root, \
+    jax.config.jax_compilation_cache_dir
 n = sum(len(fs) for _, _, fs in os.walk(root))
 sys.exit(0 if n > 0 else 3)
 """
 
 
-@pytest.mark.slow
-def test_persistent_compile_cache_populates(tmp_path):
-    """REPRO_JAX_CACHE_DIR points jax's persistent compilation cache
-    at an on-disk directory so repeat processes skip the device
-    program's XLA compile; the dispatch must write entries there."""
+def test_compile_cache_follows_jax_env(tmp_path):
+    """With $JAX_COMPILATION_CACHE_DIR set, the program sets no cache
+    directory of its own: JAX's, as the variable set it, receives the
+    device program's compile."""
     import os
     import subprocess
     import sys
 
+    import repro
+
     env = dict(os.environ)
     env.update({"JAX_PLATFORMS": "cpu",
-                "REPRO_JAX_CACHE_DIR": str(tmp_path / "jax_cache")})
-    out = subprocess.run([sys.executable, "-c", _PERSIST_CACHE_SCRIPT],
-                         env=env, capture_output=True, text=True)
+                "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "jax_cache"),
+                "PYTHONPATH": str(Path(repro.__file__).resolve().parents[1])})
+    out = subprocess.run([sys.executable, "-c", _ENV_CACHE_SCRIPT],
+                         env=env, capture_output=True, text=True,
+                         cwd=tmp_path)
     assert out.returncode == 0, (out.returncode, out.stderr)
+    assert not (tmp_path / "results").exists()
 
 
-def test_persistent_cache_env_off_disables(monkeypatch):
-    """'off' (and empty) values disable persistence without touching
-    jax config — the spans tests rely on a cold compile per process."""
+def test_compile_cache_default_is_checkout_anchored(monkeypatch, tmp_path):
+    """Without the variable, the cache lives in <checkout>/results/
+    jax_cache whatever the cwd: the path is part of the cache key."""
+    import jax
+
+    import repro
     from repro.sweep import device as dev
 
-    monkeypatch.setattr(dev, "_PERSIST_CONFIGURED", False)
-    monkeypatch.setenv(dev.ENV_JAX_CACHE_DIR, "off")
-    import jax
-    before = jax.config.jax_compilation_cache_dir
-    dev._maybe_persistent_cache()
-    assert jax.config.jax_compilation_cache_dir == before
+    checkout = Path(repro.__file__).resolve().parents[2]
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    before = {k: getattr(jax.config, k) for k in keys}
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.chdir(tmp_path)
+    monkeypatch.setattr(dev, "_CACHE_CONFIGURED", False)
+    try:
+        dev._configure_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == \
+            str(checkout / "results" / "jax_cache")
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)
+    assert not (tmp_path / "results").exists()

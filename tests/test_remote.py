@@ -158,6 +158,19 @@ def test_choose_mode():
     assert choose_mode("auto", payload) == "device"  # single-site shard
 
 
+def test_device_worker_refuses_silent_cpu_fallback(monkeypatch):
+    # the CPU is the device program's backend only where JAX_PLATFORMS
+    # names it; otherwise a device-mode worker without an accelerator
+    # fails and says why instead of running on the host
+    from repro.sweep.worker import require_device_backend
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    require_device_backend()
+    monkeypatch.delenv("JAX_PLATFORMS")
+    with pytest.raises(RuntimeError, match="no accelerator"):
+        require_device_backend()
+
+
 # --------------------------------------------------------------------------
 # runner integration + validation
 # --------------------------------------------------------------------------

@@ -224,3 +224,28 @@ def test_peak_rss_includes_pool_children():
     after = _peak_rss_mb()
     assert after >= before
     assert after >= 250.0     # the child's footprint, not the parent's
+
+
+def _child_jax_platforms(_):
+    import jax
+    return (jax.config.jax_platforms,
+            sorted({d.platform for d in jax.devices()}))
+
+
+def test_pool_children_start_cpu_only(monkeypatch):
+    """A parent on a chip host holds the accelerator; the host-path
+    pool children of SweepRunner(workers=2) start JAX on the CPU alone,
+    whatever platforms the inherited environment names (here one no
+    backend answers to, so any child that honoured it would fail)."""
+    from repro.sweep.runner import host_pool
+
+    monkeypatch.setenv("JAX_PLATFORMS", "accelerator")
+    with host_pool(2) as pool:
+        seen = list(pool.map(_child_jax_platforms, range(2)))
+    assert seen == [("cpu", ["cpu"])] * 2
+
+    scenarios = GridSpec(base=tiny_base(8), axes={
+        "workload.qps": [2.0, 4.0]}).expand()
+    par, _ = SweepRunner(cache=None, workers=2).run(scenarios)
+    ser, _ = SweepRunner(cache=None, workers=1).run(scenarios)
+    assert [r["metrics"] for r in par] == [r["metrics"] for r in ser]
