@@ -93,8 +93,7 @@ def device_grid(name: str, devices: int) -> dict:
     ref, _, ref_derived = run_sweep(name, mode="event_loop")
     errs = check_contract(ref, recs)
     require(derived == ref_derived, name, derived, ref_derived)
-    first = {k: v["total_s"] for k, v in phases.items()
-             if k in ("device.jit_compile_and_execute", "device.execute")}
+    first = phases["device.execute"]["total_s"]
 
     scenarios = SWEEPS[name].build(False)
     PROFILER.enable(reset=True)
@@ -103,11 +102,13 @@ def device_grid(name: str, devices: int) -> dict:
     finally:
         PROFILER.disable()
     require(stats.platform == "tpu" and stats.devices == devices, stats)
+    require(stats.compiles == 0, name, "warm run compiled", stats.compiles)
     require([r["metrics"] for r in again] == [r["metrics"] for r in recs],
             name, "device records differ between two runs")
     row = {"grid": name, "scenarios": len(scenarios),
            "trace_groups": stats.trace_groups, "bucket": stats.bucket,
            "devices": stats.devices, "first_call_s": first,
+           "warm_compiles": stats.compiles,
            "warm_dispatch_s": PROFILER.aggregate()["device.execute"][
                "total_s"],
            "max_rel_err": max(errs.values()), "rel_err_by_col": errs}

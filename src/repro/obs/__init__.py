@@ -12,9 +12,11 @@ Two clocks, one contract:
   runs are bitwise identical to un-instrumented ones (neutrality,
   pinned by tests/test_obs.py).
 * **wall-clock** — the ``SpanProfiler`` (module-global ``PROFILER``)
-  over the sweep pipeline: cache lookups, trace grouping, event-loop
-  runs, stacked passes, device-mode jit compile vs execute, worker
-  fan-out.
+  over the sweep pipeline: cache lookups and key digests, trace
+  grouping, event-loop runs, stacked passes, the device mode's
+  pad/stack, host-device transfers, program run and record assembly,
+  worker fan-out. Enabled, each span is also a
+  ``jax.profiler.TraceAnnotation``, on the device trace's clock.
 
 On top of the probe layer:
 

@@ -1,11 +1,16 @@
 """Wall-clock span profiler for the sweep pipeline.
 
-``SpanProfiler`` records nestable named spans (cache lookup, trace
-grouping, event-loop runs, stacked passes, device jit compile vs
-execute, worker fan-out) against ``time.perf_counter``. Disabled — the
-default — ``span()`` returns a shared no-op context manager, so
-instrumented call sites cost one attribute check when profiling is
-off.
+``SpanProfiler`` records nestable named spans (cache lookup and key
+digests, trace grouping, event-loop runs, stacked passes, the device
+path's pad/stack, transfers, program run and record assembly, worker
+fan-out) against ``time.perf_counter``. Disabled — the default —
+``span()`` returns a shared no-op context manager, so instrumented
+call sites cost one attribute check when profiling is off.
+
+Enabled, each span also opens a ``jax.profiler.TraceAnnotation`` of
+its name, so every span appears as a host event in any ``jax.profiler``
+trace recorded meanwhile, on the same clock as the device's operations
+(TensorBoard or Perfetto show the host phases beside the device ops).
 
 The module-level ``PROFILER`` is the process-wide instance the sweep
 pipeline instruments against; enable it via ``PROFILER.enable()`` (the
@@ -37,13 +42,15 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_prof", "name", "t0", "depth")
+    __slots__ = ("_prof", "name", "t0", "depth", "_ann")
 
     def __init__(self, prof: "SpanProfiler", name: str):
         self._prof = prof
         self.name = name
 
     def __enter__(self):
+        self._ann = self._prof._annotation(self.name)
+        self._ann.__enter__()
         self.depth = self._prof._depth
         self._prof._depth += 1
         self.t0 = time.perf_counter()
@@ -53,6 +60,7 @@ class _Span:
         dur = time.perf_counter() - self.t0
         self._prof._depth -= 1
         self._prof._events.append((self.name, self.t0, dur, self.depth))
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -67,10 +75,15 @@ class SpanProfiler:
         self._events: List[Tuple[str, float, float, int]] = []
         # phase aggregates merged from other processes
         self._merged: Dict[str, Dict[str, float]] = {}
+        # jax.profiler.TraceAnnotation, bound by enable()
+        self._annotation = None
 
     def enable(self, reset: bool = False) -> None:
         if reset:
             self.reset()
+        if self._annotation is None:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
         self.enabled = True
 
     def disable(self) -> None:

@@ -82,6 +82,7 @@ class DeviceStats:
     platform: str = ""       # jax platform the program ran on ("tpu")
     device_kind: str = ""    # e.g. "TPU v5 lite"; "cpu" on the host
     bucket: Tuple[int, int, int] = (0, 0, 0)   # padded (G, S, K)
+    compiles: int = 0        # executables built or loaded in the dispatch
 
 
 def _next_pow2(n: int) -> int:
@@ -127,10 +128,33 @@ def _group_kernel(comp_pre, comp_dec, comp_score, comp_kv,
 _PROGRAM = None
 _PMAP_PROGRAMS: Dict[int, object] = {}
 
-# padded shapes this process has already dispatched: a new (G, S, K)
-# bucket pays XLA compilation inside the call, a seen one replays the
-# jit cache — the wall-clock profiler labels the two differently
-_SEEN_SHAPES: set = set()
+#: JAX's monitoring event around each executable's backend compile or
+#: persistent-cache load (``compile_or_get_cached``): once per
+#: executable either way. A cache load also records
+#: ``/jax/compilation_cache/cache_hits``, so counting both events would
+#: count a load twice
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+# executables this process has built or loaded, counted by one
+# jax.monitoring listener that the first dispatch registers
+_COMPILES = 0
+_LISTENING = False
+
+
+def _on_duration(name, secs, **kw):
+    global _COMPILES
+    if name == COMPILE_EVENT:
+        _COMPILES += 1
+
+
+def _listen_for_compiles() -> None:
+    global _LISTENING
+    if _LISTENING:
+        return
+    _LISTENING = True
+    import jax.monitoring
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
 
 #: persistent compilation cache when ``$JAX_COMPILATION_CACHE_DIR`` is
 #: unset: anchored on the checkout, not the cwd, because the directory
@@ -231,7 +255,8 @@ def execute_device_grid(scenarios: Sequence[Scenario],
                                     single_site_metrics,
                                     single_site_record)
 
-    groups = group_by_trace(scenarios)
+    with PROFILER.span("device.group"):
+        groups = group_by_trace(scenarios)
     stats = DeviceStats(trace_groups=len(groups))
     records: List[Optional[dict]] = [None] * len(scenarios)
 
@@ -251,46 +276,50 @@ def execute_device_grid(scenarios: Sequence[Scenario],
         results, sim_elapsed = _acquire_results(scenarios, single, stats)
 
     # ---- pad + ragged-stack into one (G, S) / (G, K) tensor set ----
-    n_g = len(single)
-    gp = _next_pow2(n_g)
-    sp = _next_pow2(max(max(len(r.stages) for r in results), 1))
-    kp = _next_pow2(max(max(len(g) for g in single), 1))
-    comp = np.zeros((4, gp, sp))
-    params = np.ones((gp, len(PARAMS_FIELDS)))
-    powerp = np.zeros((gp, 5), np.float32)
-    powerp[:, 2] = 0.5                   # padded groups: x = 0/0 guard
-    powerp[:, 3] = 1.0
-    ndev = np.ones(gp)
-    phi = np.zeros(gp)
-    pues = np.zeros((gp, kp))
-    cis = np.zeros((gp, kp))
-    for gi, (g, res) in enumerate(zip(single, results)):
-        cfg = res.cfg
-        tr = res.stages
-        m = len(tr)
-        comp[0, gi, :m] = tr.n_prefill_tokens
-        comp[1, gi, :m] = tr.n_decode_tokens
-        comp[2, gi, :m] = tr.score_flops
-        comp[3, gi, :m] = tr.kv_rw_bytes
-        em = cached_execution_model(cfg.model, cfg.device, cfg.tp,
-                                    cfg.pp, cfg.execmodel)
-        params[gi] = em.params_vector()
-        dev = DEVICES[cfg.device]
-        powerp[gi] = np.asarray(
-            [dev.p_idle, dev.p_max_inst, dev.mfu_sat, dev.gamma,
-             dev.p_max_inst - dev.p_idle], np.float32)
-        ndev[gi] = float(cfg.n_devices)
-        phi[gi] = dev.embodied_kg_per_hour
-        for k, i in enumerate(g):
-            pues[gi, k] = scenarios[i].pue
-            cis[gi, k] = scenarios[i].grid_ci
+    with PROFILER.span("device.pad_stack"):
+        n_g = len(single)
+        gp = _next_pow2(n_g)
+        sp = _next_pow2(max(max(len(r.stages) for r in results), 1))
+        kp = _next_pow2(max(max(len(g) for g in single), 1))
+        comp = np.zeros((4, gp, sp))
+        params = np.ones((gp, len(PARAMS_FIELDS)))
+        powerp = np.zeros((gp, 5), np.float32)
+        powerp[:, 2] = 0.5               # padded groups: x = 0/0 guard
+        powerp[:, 3] = 1.0
+        ndev = np.ones(gp)
+        phi = np.zeros(gp)
+        pues = np.zeros((gp, kp))
+        cis = np.zeros((gp, kp))
+        for gi, (g, res) in enumerate(zip(single, results)):
+            cfg = res.cfg
+            tr = res.stages
+            m = len(tr)
+            comp[0, gi, :m] = tr.n_prefill_tokens
+            comp[1, gi, :m] = tr.n_decode_tokens
+            comp[2, gi, :m] = tr.score_flops
+            comp[3, gi, :m] = tr.kv_rw_bytes
+            em = cached_execution_model(cfg.model, cfg.device, cfg.tp,
+                                        cfg.pp, cfg.execmodel)
+            params[gi] = em.params_vector()
+            dev = DEVICES[cfg.device]
+            powerp[gi] = np.asarray(
+                [dev.p_idle, dev.p_max_inst, dev.mfu_sat, dev.gamma,
+                 dev.p_max_inst - dev.p_idle], np.float32)
+            ndev[gi] = float(cfg.n_devices)
+            phi[gi] = dev.embodied_kg_per_hour
+            for k, i in enumerate(g):
+                pues[gi, k] = scenarios[i].pue
+                cis[gi, k] = scenarios[i].grid_ci
 
     # ---- the single dispatch for the whole grid ----
     # enable_x64 is scoped: the program traces/executes in f64 without
     # flipping the process-global default (kernel/launcher tests in the
-    # same process rely on f32 defaults). With >1 local accelerator the
-    # padded group axis shards (D, G/D) across devices via pmap —
-    # always exact: gp is a power of two, and so is D
+    # same process rely on f32 defaults), and the inputs are placed on
+    # the device inside it, so they stay f64. With >1 local accelerator
+    # the padded group axis shards (D, G/D) across devices via pmap —
+    # always exact: gp is a power of two, and so is D. The blocks wait
+    # for the device only when profiling, so that each span times what
+    # its name says, and the unprofiled path gains no sync
     local = jax.local_devices()
     n_local = min(len(local), max_devices or len(local))
     d = 1
@@ -298,50 +327,58 @@ def execute_device_grid(scenarios: Sequence[Scenario],
         d *= 2
     args = (comp[0], comp[1], comp[2], comp[3],
             params, powerp, ndev, phi, pues, cis)
-    shape_sig = (gp, sp, kp, d)
-    dispatch_span = ("device.jit_compile_and_execute"
-                     if shape_sig not in _SEEN_SHAPES
-                     else "device.execute")
-    with jax.enable_x64(True):
-        with PROFILER.span(dispatch_span):
+    _listen_for_compiles()
+    compiles0 = _COMPILES
+    with jax.enable_x64(True), PROFILER.span("device.execute"):
+        with PROFILER.span("device.h2d"):
             if d > 1:
-                sharded = tuple(
-                    a.reshape((d, gp // d) + a.shape[1:]) for a in args)
-                out = _pmap_program(d)(*sharded)
-                e_sum, m_sum, dur, peak, op_g, emb_g = tuple(
-                    np.asarray(o).reshape((gp,) + np.asarray(o).shape[2:])
-                    for o in out)
+                mesh = jax.sharding.Mesh(np.asarray(local[:d]), ("d",))
+                placed = jax.device_put(
+                    tuple(a.reshape((d, gp // d) + a.shape[1:])
+                          for a in args),
+                    jax.sharding.NamedSharding(
+                        mesh, jax.sharding.PartitionSpec("d")))
             else:
-                out = _program()(*args)
-                e_sum, m_sum, dur, peak, op_g, emb_g = tuple(
-                    np.asarray(o) for o in out)
-    _SEEN_SHAPES.add(shape_sig)
+                placed = jax.device_put(args, local[0])
+            if PROFILER.enabled:
+                jax.block_until_ready(placed)
+        with PROFILER.span("device.run"):
+            out = (_pmap_program(d) if d > 1 else _program())(*placed)
+            if PROFILER.enabled:
+                jax.block_until_ready(out)
+        with PROFILER.span("device.d2h"):
+            host = [np.asarray(o) for o in out]
+    if d > 1:
+        host = [h.reshape((gp,) + h.shape[2:]) for h in host]
+    e_sum, m_sum, dur, peak, op_g, emb_g = host
+    stats.compiles = _COMPILES - compiles0
     stats.devices = d
     stats.platform = local[0].platform
     stats.device_kind = local[0].device_kind
     stats.bucket = (gp, sp, kp)
 
     # ---- record assembly through the shared single-site path ----
-    for gi, (g, res) in enumerate(zip(single, results)):
-        scs = [scenarios[i] for i in g]
-        cfg = res.cfg
-        shared_m = shared_result_metrics(res)
-        reps = reports_from_sums(
-            float(e_sum[gi]), float(m_sum[gi]), float(dur[gi]),
-            float(peak[gi]), n_devices=cfg.n_devices,
-            pues=[sc.pue for sc in scs])
-        emb = float(emb_g[gi])
-        ops = [float(o) for o in op_g[gi, :len(g)]]
-        carbons = reports_from_arrays(
-            ops, [emb] * len(g), [o + emb for o in ops],
-            [sc.grid_ci for sc in scs])
-        for i, sc, rep, carbon in zip(g, scs, reps, carbons):
-            rec_t0 = time.perf_counter() - sim_elapsed[gi]
-            metrics = single_site_metrics(res, sc, rep, carbon=carbon,
-                                          shared=shared_m)
-            records[i] = single_site_record(
-                sc, metrics, rec_t0, mode="device",
-                trace_scenarios=len(scs))
+    with PROFILER.span("device.assemble_records"):
+        for gi, (g, res) in enumerate(zip(single, results)):
+            scs = [scenarios[i] for i in g]
+            cfg = res.cfg
+            shared_m = shared_result_metrics(res)
+            reps = reports_from_sums(
+                float(e_sum[gi]), float(m_sum[gi]), float(dur[gi]),
+                float(peak[gi]), n_devices=cfg.n_devices,
+                pues=[sc.pue for sc in scs])
+            emb = float(emb_g[gi])
+            ops = [float(o) for o in op_g[gi, :len(g)]]
+            carbons = reports_from_arrays(
+                ops, [emb] * len(g), [o + emb for o in ops],
+                [sc.grid_ci for sc in scs])
+            for i, sc, rep, carbon in zip(g, scs, reps, carbons):
+                rec_t0 = time.perf_counter() - sim_elapsed[gi]
+                metrics = single_site_metrics(res, sc, rep, carbon=carbon,
+                                              shared=shared_m)
+                records[i] = single_site_record(
+                    sc, metrics, rec_t0, mode="device",
+                    trace_scenarios=len(scs))
     return [r for r in records if r is not None], stats
 
 

@@ -275,6 +275,7 @@ class SweepStats:
     devices: int = 0
     device_platform: str = ""
     device_kind: str = ""
+    compiles: int = 0         # device mode: executables built or loaded
     # ResultCache effectiveness over this run (lookup-phase deltas);
     # cache_attached distinguishes a no-cache run from an all-miss one
     cache_attached: bool = False
@@ -299,7 +300,7 @@ class SweepStats:
                   if self.mode == "device" and self.executed else "")
         if self.device_platform:
             shared += (f", on {self.devices}x {self.device_platform} "
-                       f"({self.device_kind})")
+                       f"({self.device_kind}), {self.compiles} compile(s)")
         eff = (f", cache {self.cache_memo} memo / {self.cache_disk} disk"
                f" / {self.cache_miss} miss"
                if self.cache_attached else "")
@@ -425,17 +426,19 @@ class SweepRunner:
         misses: List[int] = []          # first index per uncached key
         dup_of: Dict[str, List[int]] = {}   # key -> later same-key idxs
         with PROFILER.span("cache.lookup"):
-            for i, sc in enumerate(scenarios):
-                hit = (self.cache.get(sc.key)
+            with PROFILER.span("sweep.key_digest"):
+                keys = [sc.key for sc in scenarios]
+            for i, (sc, key) in enumerate(zip(scenarios, keys)):
+                hit = (self.cache.get(key)
                        if self.cache is not None else None)
                 if hit is not None:
                     records[i] = self._rebind(hit, sc)
                     stats.cache_hits += 1
-                elif sc.key in dup_of:  # same config earlier in this run
-                    dup_of[sc.key].append(i)
+                elif key in dup_of:     # same config earlier in this run
+                    dup_of[key].append(i)
                     stats.cache_hits += 1
                 else:
-                    dup_of[sc.key] = []
+                    dup_of[key] = []
                     misses.append(i)
         if self.cache is not None:
             c1 = self.cache.counters
@@ -561,6 +564,7 @@ class SweepRunner:
         stats.devices = dstats.devices
         stats.device_platform = dstats.platform
         stats.device_kind = dstats.device_kind
+        stats.compiles = dstats.compiles
         return fresh
 
 

@@ -287,6 +287,72 @@ def test_jax_backend_parity_all_paper_models(name):
 
 
 # --------------------------------------------------------------------------
+# profiled dispatch, compile counts, the program's name
+# --------------------------------------------------------------------------
+
+def test_records_bitwise_equal_with_profiler_on_and_off():
+    """The profiler adds spans and device syncs, never other numbers:
+    on the perf grid (event loops and replay) the records are equal."""
+    from repro.obs.spans import PROFILER
+
+    scenarios = SWEEPS["perf"].build(True, n_requests=16)
+    off, _ = execute_device_grid(scenarios)
+    PROFILER.enable(reset=True)
+    try:
+        on, _ = execute_device_grid(scenarios)
+    finally:
+        PROFILER.disable()
+        PROFILER.reset()
+    assert [r["key"] for r in on] == [r["key"] for r in off]
+    assert [r["metrics"] for r in on] == [r["metrics"] for r in off]
+
+
+def test_compiles_counted_at_a_new_bucket_and_not_on_a_repeat(monkeypatch):
+    """``DeviceStats.compiles`` counts the executables JAX builds in the
+    dispatch: the one grid program when it was never compiled
+    (persistent cache off), none when the same bucket runs again."""
+    import jax
+
+    from repro.sweep import device as dev
+
+    scenarios = SWEEPS["fig4"].build(True, n_requests=8)
+    monkeypatch.setattr(dev, "_PROGRAM", None)
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        _, first = execute_device_grid(scenarios)
+        _, again = execute_device_grid(scenarios)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", before)
+    assert first.compiles == 1
+    assert again.compiles == 0
+    assert again.bucket == first.bucket
+    _, stats = SweepRunner(cache=None, mode="device").run(scenarios)
+    assert stats.compiles == 0
+    assert "0 compile(s)" in stats.summary()
+
+
+def test_grid_program_module_is_named_for_the_kernel():
+    """The device trace finds the grid program by its module name,
+    ``jit__group_kernel``: renaming ``_group_kernel`` fails here rather
+    than silencing the benchmark's roofline reading."""
+    import jax
+
+    from repro.sim.execmodel import PARAMS_FIELDS
+    from repro.sweep import device as dev
+
+    g, s, k = 2, 8, 4
+    shapes = [(g, s)] * 4 + [(g, len(PARAMS_FIELDS)), (g, 5), (g,), (g,),
+                             (g, k), (g, k)]
+    with jax.enable_x64(True):
+        args = [jax.ShapeDtypeStruct(
+            sh, np.float32 if i == 5 else np.float64)
+            for i, sh in enumerate(shapes)]
+        text = dev._program().lower(*args).as_text()
+    assert "module @jit__group_kernel" in text
+
+
+# --------------------------------------------------------------------------
 # multi-device sharded dispatch + persistent compilation cache
 # --------------------------------------------------------------------------
 
@@ -298,12 +364,18 @@ assert jax.local_device_count() == 2, jax.local_device_count()
 from repro.sweep import SWEEPS, SweepRunner
 from repro.sweep.device import (DEVICE_MODE_RTOL, execute_device_grid,
                                 records_max_rel_err)
+from repro.obs.spans import PROFILER
 scenarios = SWEEPS["fig4"].build(True)
 recs, dstats = execute_device_grid(scenarios)
+PROFILER.enable()
+profiled, _ = execute_device_grid(scenarios)
+PROFILER.disable()
 ref, _ = SweepRunner(cache=None, mode="event_loop").run(scenarios)
 print(json.dumps({"devices": dstats.devices,
                   "err": records_max_rel_err(recs, ref),
-                  "rtol": DEVICE_MODE_RTOL}))
+                  "rtol": DEVICE_MODE_RTOL,
+                  "profiled_equal": [r["metrics"] for r in profiled]
+                  == [r["metrics"] for r in recs]}))
 """
 
 
@@ -326,6 +398,7 @@ def test_sharded_dispatch_across_two_host_devices():
     res = json.loads(out.stdout.strip().splitlines()[-1])
     assert res["devices"] == 2
     assert res["err"] <= res["rtol"]
+    assert res["profiled_equal"]
 
 
 _ENV_CACHE_SCRIPT = """
@@ -333,11 +406,12 @@ import os, sys
 import jax
 from repro.sweep import SWEEPS
 from repro.sweep.device import execute_device_grid
-execute_device_grid(SWEEPS["fig4"].build(True))
+_, stats = execute_device_grid(SWEEPS["fig4"].build(True))
 root = os.environ["JAX_COMPILATION_CACHE_DIR"]
 assert jax.config.jax_compilation_cache_dir == root, \
     jax.config.jax_compilation_cache_dir
 n = sum(len(fs) for _, _, fs in os.walk(root))
+print(stats.compiles)
 sys.exit(0 if n > 0 else 3)
 """
 
@@ -356,10 +430,14 @@ def test_compile_cache_follows_jax_env(tmp_path):
     env.update({"JAX_PLATFORMS": "cpu",
                 "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "jax_cache"),
                 "PYTHONPATH": str(Path(repro.__file__).resolve().parents[1])})
-    out = subprocess.run([sys.executable, "-c", _ENV_CACHE_SCRIPT],
-                         env=env, capture_output=True, text=True,
-                         cwd=tmp_path)
-    assert out.returncode == 0, (out.returncode, out.stderr)
+    for _ in range(2):
+        out = subprocess.run([sys.executable, "-c", _ENV_CACHE_SCRIPT],
+                             env=env, capture_output=True, text=True,
+                             cwd=tmp_path)
+        assert out.returncode == 0, (out.returncode, out.stderr)
+        # the first process builds the program, the second loads it
+        # from the persistent cache: one executable each
+        assert out.stdout.split()[-1] == "1", out.stdout
     assert not (tmp_path / "results").exists()
 
 

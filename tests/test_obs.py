@@ -254,6 +254,89 @@ def test_span_profiler_disabled_records_nothing():
     assert prof.spans() == [] and prof.aggregate() == {}
 
 
+#: every span of the device path, with the span it nests in
+DEVICE_SPAN_PARENTS = {
+    "sweep.key_digest": "cache.lookup",
+    "device.group": "device.grid",
+    "device.acquire_traces": "device.grid",
+    "device.pad_stack": "device.grid",
+    "device.execute": "device.grid",
+    "device.h2d": "device.execute",
+    "device.run": "device.execute",
+    "device.d2h": "device.execute",
+    "device.assemble_records": "device.grid",
+}
+
+
+def _parent(spans, child):
+    """Name of the span one level up that encloses ``child``."""
+    name, t0, dur, depth = child
+    for n, s, d, dep in spans:
+        if dep == depth - 1 and s <= t0 and t0 + dur <= s + d:
+            return n
+    return None
+
+
+def _device_sweep():
+    return SweepRunner(cache=None, mode="device").run(
+        SWEEPS["fig1"].build(True, n_requests=8))
+
+
+def test_device_sweep_spans_nest_under_their_parents():
+    _device_sweep()                     # compile outside the profile
+    PROFILER.enable(reset=True)
+    _device_sweep()
+    PROFILER.disable()
+    spans = PROFILER.spans()
+    names = [n for n, *_ in spans]
+    for child, parent in DEVICE_SPAN_PARENTS.items():
+        mine = [sp for sp in spans if sp[0] == child]
+        assert len(mine) == 1, (child, names)
+        assert _parent(spans, mine[0]) == parent, (child, spans)
+    assert "device.jit_compile_and_execute" not in names
+
+
+def test_disabled_profiler_records_nothing_in_a_device_sweep():
+    PROFILER.reset()
+    _device_sweep()
+    assert PROFILER.spans() == [] and PROFILER.aggregate() == {}
+
+
+def test_spans_are_host_events_of_a_profiler_trace(tmp_path):
+    """Each program span is a ``TraceAnnotation``: a ``jax.profiler``
+    trace holds one host event per span, with the same name, nested
+    as the spans are, on the trace's own clock."""
+    import collections
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    _device_sweep()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    PROFILER.enable(reset=True)
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        _device_sweep()
+    finally:
+        jax.profiler.stop_trace()
+        PROFILER.disable()
+    want = collections.Counter(n for n, *_ in PROFILER.spans())
+    pb = sorted(glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                              / "*.xplane.pb")))[-1]
+    events = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+              for plane in ProfileData.from_file(pb).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events
+              if ev.name in want]
+    assert collections.Counter(n for n, _, _ in events) == want
+    at = {n: (s, e) for n, s, e in events}
+    for child, parent in DEVICE_SPAN_PARENTS.items():
+        assert at[parent][0] <= at[child][0] <= at[child][1] \
+            <= at[parent][1], (child, parent)
+
+
 def test_span_profiler_merge_folds_worker_aggregates():
     prof = SpanProfiler()
     prof.enable()
