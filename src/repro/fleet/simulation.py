@@ -78,6 +78,10 @@ class LoopSite:
         # transitions; None (default) keeps every hook dead
         self.probe = None
         self.site_index = 0
+        # iterations ``drive`` ran on this site, and how many of them
+        # it advanced in decode-run array steps (``_advance_decode_run``)
+        self.loop_iterations = 0
+        self.ff_iterations = 0
 
     def add(self, req: Request):
         """Route one request into the site. Replicas that were idle
@@ -107,8 +111,69 @@ class LoopSite:
         active set changed (the loop then re-selects its event)."""
         return False
 
+    def can_control(self) -> bool:
+        """Whether ``maybe_control`` may act (or keep state) at an
+        event; ``drive`` then takes every iteration one at a time."""
+        return False
+
     def stage_log(self) -> StageTrace:
         return self.trace.build()
+
+
+def _advance_decode_run(st: LoopSite, i: int, rep, decodes: List[Request],
+                        ctxs: List[int], now: float, t0: float,
+                        lt: float, le: float) -> Optional[float]:
+    """Advance replica ``i``'s run of identical decode-only iterations
+    in one array step, bit-identical to taking them one by one through
+    ``drive``. Returns the clock at the run's end, before its last
+    iteration is committed (the caller runs that one through
+    ``complete_iteration``), or None when the run is one iteration long.
+
+    Iteration ``j`` of the run decodes the same requests at contexts
+    ``ctxs + j``: nothing is admitted (the running set stays, the KV
+    budget's free room only shrinks) and nothing completes before the
+    ``k``-th iteration, ``k`` the fewest tokens any decode has left.
+    The loop would take iteration ``j >= 1`` next iff its start
+    ``c_j`` precedes the next ready time and the clock of every
+    replica with work ahead of this one in the loop's order
+    (``c_j < lt``), and ties at most the replicas after it and the
+    horizon (``c_j <= le``); the run stops at the first that fails.
+    """
+    span = min(lt, le) - now
+    if not span >= t0:
+        return None     # the next iteration would not start before lt
+    k = min(r.decode_tokens - r.decoded for r in decodes)
+    if k < 2:
+        return None
+    if span < (k - 1) * t0:
+        # costs only grow with context, so no iteration past
+        # now + j * t0 >= span starts in time; +2 covers rounding
+        k = int(span / t0) + 2
+    n = len(decodes)
+    t, f_mlp, f_attn, mfu, score, kv = st.exec_model.decode_run(ctxs, k)
+    clock = np.cumsum(np.concatenate(([now], t)))   # drive's left fold
+    ok = (clock[1:k] < lt) & (clock[1:k] <= le)
+    m = k if ok.all() else 1 + int(ok.argmin())
+    if m < 2:
+        return None
+
+    # an (iteration, pipeline stage) block of rows, as drive appends them
+    pp = st.pp
+    ps = np.arange(pp, dtype=np.float64)
+    t = t[:m, None]
+    st.trace.extend({
+        "start_s": clock[:m, None] + ps * t / max(pp, 1), "dur_s": t,
+        "flops_mlp": f_mlp, "flops_attn": f_attn[:m, None],
+        "mfu": mfu[:m, None], "n_prefill_tokens": 0.0,
+        "n_decode_tokens": float(n), "replica": i * pp + ps,
+        "batch_size": float(n), "score_flops": score[:m, None],
+        "kv_rw_bytes": kv[:m, None]})
+    for r in decodes:
+        r.decoded += m - 1
+    rep.kv_tokens += n * (m - 1)
+    st.loop_iterations += m
+    st.ff_iterations += m
+    return float(clock[m])
 
 
 def drive(sites: List[LoopSite], route, requests: List[Request],
@@ -128,9 +193,16 @@ def drive(sites: List[LoopSite], route, requests: List[Request],
     ``probe`` (``repro.obs.Probe``) observes committed stages; it is
     read-only and costs nothing when None — probe-off runs are bitwise
     identical to probe-attached ones (the neutrality contract).
+
+    A decode-only iteration opens a run of iterations with the same
+    batch; ``_advance_decode_run`` takes the whole run in one array
+    step, with the same result bit for bit. A probe (which observes
+    every stage) or a site that can autoscale keeps every iteration on
+    the one-at-a-time path.
     """
     pending = sorted(requests, key=lambda r: r.ready_s)
     pi = 0
+    fast = probe is None and not any(st.can_control() for st in sites)
     pairs = [(s, i) for s, st in enumerate(sites)
              for i in range(len(st.clocks))]
     stuck = set()       # replicas whose head-of-queue can never admit
@@ -179,6 +251,31 @@ def drive(sites: List[LoopSite], route, requests: List[Request],
         ctxs = [r.prefill_tokens + r.decoded for r in decodes]
         cost, npt, ndec, f_score, kv_rw = st.exec_model.stage_cost_scalar(
             plens, ctxs, offs)
+
+        if fast and not prefills:
+            # a run's iterations start before the next ready time and
+            # the clocks of the replicas ahead of this one (``min``
+            # takes the first of equal clocks), and no later than the
+            # clocks of those after it and the horizon
+            lt = pending[pi].ready_s if pi < len(pending) else math.inf
+            le = max_sim_s
+            ahead = True
+            for q in candidates:
+                if q == (s, i):
+                    ahead = False
+                elif ahead:
+                    lt = min(lt, sites[q[0]].clocks[q[1]])
+                else:
+                    le = min(le, sites[q[0]].clocks[q[1]])
+            end = _advance_decode_run(st, i, rep, decodes, ctxs, now,
+                                      cost.t_total, lt, le)
+            if end is not None:
+                st.clocks[i] = end
+                st.note_done(rep.complete_iteration([], decodes, end))
+                if end > max_sim_s:
+                    break
+                continue
+        st.loop_iterations += 1
 
         # one record per pipeline stage (replica-stage granularity)
         bs = len(prefills) + len(decodes)
@@ -244,6 +341,9 @@ class _SiteRuntime(LoopSite):
         if self.controller is None:
             return False
         return self.controller.maybe_control(self, t_s)
+
+    def can_control(self) -> bool:
+        return self.controller is not None
 
     # ---- FleetRouter protocol ----
     def outstanding_tokens(self) -> int:

@@ -181,6 +181,25 @@ def _roofline(prefill_tokens, decode_count, score_flops, kv_rw_bytes,
     return tuple(out)
 
 
+def _live_roofline(tokens, f_score, kv_rw, p, maximum):
+    """The roofline of stages with ``tokens > 0`` in the scalar path's
+    operation order: ``tokens`` is a float, ``f_score`` and ``kv_rw``
+    floats (with ``maximum=max``) or arrays over stages (with
+    ``np.maximum``). Returns ``StageCost``'s fields in order."""
+    f_mlp = tokens * p.fpt_mlp
+    f_attn = tokens * p.fpt_proj + f_score
+    flops_st = (f_mlp + f_attn) / p.pp
+    mem_st = (p.weight_bytes + kv_rw
+              + tokens * p.act_bytes_per_token) / p.pp
+    eff = p.eff_max * tokens / (tokens + p.eff_half_tokens)
+    t_comp = flops_st / (eff * p.peak_chips)
+    t_mem = mem_st / p.hbm_chips
+    t_coll = tokens * p.coll_s_per_token
+    t = maximum(t_comp, t_mem) + p.coll_scale * t_coll + p.overhead_s
+    return (t, t_comp, t_mem, t_coll, f_mlp / p.pp, f_attn / p.pp,
+            flops_st / (p.peak_chips * t))
+
+
 class ExecutionModel:
     def __init__(self, model: ModelConfig, device: DeviceProfile,
                  tp: int = 1, pp: int = 1,
@@ -374,44 +393,58 @@ class ExecutionModel:
         score_flops, kv_rw_bytes)`` — the cost plus the stage's
         StageBatch aggregates as plain floats (what the trace logs).
         """
-        plens = np.asarray(prefill_lens, np.float64)
         ctxs = np.asarray(decode_ctxs, np.float64)
-        offs = (np.zeros_like(plens) if prefill_offsets is None
-                else np.asarray(prefill_offsets, np.float64))
-
-        npt = float(plens.sum())
-        nd = float(len(ctxs))
-        avg_ctx = np.maximum(offs + np.floor(plens / 2.0), 1.0)
-        f_score = (float((plens * self._score_per_token(avg_ctx)).sum())
-                   + float(self._score_per_token(ctxs).sum()))
         kvpt = self.kv_bytes_per_token
         w = self.sliding_window
-        kv_pre = (plens * kvpt + np.minimum(offs, w) * kvpt).sum()
+        nd = float(len(ctxs))
+        if len(prefill_lens):
+            plens = np.asarray(prefill_lens, np.float64)
+            offs = (np.zeros_like(plens) if prefill_offsets is None
+                    else np.asarray(prefill_offsets, np.float64))
+            npt = float(plens.sum())
+            avg_ctx = np.maximum(offs + np.floor(plens / 2.0), 1.0)
+            f_pre = float((plens * self._score_per_token(avg_ctx)).sum())
+            kv_pre = (plens * kvpt + np.minimum(offs, w) * kvpt).sum()
+        else:
+            npt = f_pre = kv_pre = 0.0      # the empty sums
+        f_score = f_pre + float(self._score_per_token(ctxs).sum())
         kv_dec = (np.minimum(ctxs, w) * kvpt + kvpt).sum()
         kv_rw = float(kv_pre + kv_dec)
 
-        p = self._params
         tokens = npt + nd
         if tokens > 0:
-            f_mlp = tokens * p.fpt_mlp
-            f_attn = tokens * p.fpt_proj + f_score
-            flops_st = (f_mlp + f_attn) / p.pp
-            mem_st = (p.weight_bytes + kv_rw
-                      + tokens * p.act_bytes_per_token) / p.pp
-            eff = p.eff_max * tokens / (tokens + p.eff_half_tokens)
-            t_comp = flops_st / (eff * p.peak_chips)
-            t_mem = mem_st / p.hbm_chips
-            t_coll = tokens * p.coll_s_per_token
-            t = (max(t_comp, t_mem) + p.coll_scale * t_coll
-                 + p.overhead_s)
-            cost = StageCost(
-                t_total=t, t_compute=t_comp, t_memory=t_mem,
-                t_collective=t_coll, flops_mlp=f_mlp / p.pp,
-                flops_attn=f_attn / p.pp,
-                mfu=flops_st / (p.peak_chips * t))
+            cost = StageCost(*_live_roofline(tokens, f_score, kv_rw,
+                                             self._params, max))
         else:
             cost = StageCost(0.0, 0.0, 0.0, 0.0, 0.0, 0.0, 0.0)
         return cost, npt, nd, f_score, kv_rw
+
+    def decode_run(self, decode_ctxs: Sequence[int], k: int):
+        """Costs of ``k`` decode-only stages over the same sequences,
+        stage ``j`` at contexts ``decode_ctxs + j`` (a run of
+        iterations in which only the contexts grow), as arrays over
+        the run.
+
+        Bit-identical to ``k`` calls of ``stage_cost_scalar`` by
+        construction: each stage's aggregates are the same expressions
+        reduced over one contiguous row of a ``(k, n)`` context grid
+        (the same pairwise sum as the 1-D one), and the roofline is the
+        scalar path's own, with the stage-invariant terms as floats.
+
+        Returns ``(t_total, flops_mlp, flops_attn, mfu, score_flops,
+        kv_rw_bytes)``; ``flops_mlp`` is one float, the same for every
+        stage.
+        """
+        grid = (np.asarray(decode_ctxs, np.float64)
+                + np.arange(k, dtype=np.float64)[:, None])
+        f_score = 0.0 + self._score_per_token(grid).sum(axis=1)
+        kvpt = self.kv_bytes_per_token
+        kv_rw = 0.0 + (np.minimum(grid, self.sliding_window) * kvpt
+                       + kvpt).sum(axis=1)
+        t, _, _, _, f_mlp, f_attn, mfu = _live_roofline(
+            0.0 + float(len(decode_ctxs)), f_score, kv_rw, self._params,
+            np.maximum)
+        return t, f_mlp, f_attn, mfu, f_score, kv_rw
 
 
 @functools.lru_cache(maxsize=512)

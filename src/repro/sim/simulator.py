@@ -75,6 +75,10 @@ class SimResult:
     stages: StageTrace
     requests: List[Request]
     cfg: SimConfig
+    # event-loop iterations, and how many of them advanced in decode-run
+    # array steps (both 0 for a trace that no loop produced)
+    loop_iterations: int = 0
+    ff_iterations: int = 0
 
     # ---- derived metrics ----
     def throughput_qps(self) -> float:
@@ -137,7 +141,9 @@ def run_simulation(cfg: SimConfig, max_sim_s: float = 10_000_000.0,
         probe.on_requests(
             np.asarray([r.arrival_s for r in requests], np.float64),
             np.asarray([r.ready_s for r in requests], np.float64))
-    return SimResult(stages=site.stage_log(), requests=requests, cfg=cfg)
+    return SimResult(stages=site.stage_log(), requests=requests, cfg=cfg,
+                     loop_iterations=site.loop_iterations,
+                     ff_iterations=site.ff_iterations)
 
 
 def energy_report(res: SimResult, pue: float = 1.2):

@@ -13,6 +13,7 @@ into the typed trace.
 from __future__ import annotations
 
 import dataclasses
+import math
 
 import numpy as np
 
@@ -83,8 +84,8 @@ class StageTrace:
 
 class StageTraceBuilder:
     """Row accumulator over a preallocated (capacity, n_fields) buffer
-    that doubles on overflow — the event loop appends scalars, the
-    arrays come out columnar."""
+    that doubles on overflow — the event loop appends scalars (or a
+    block of rows at once, ``extend``), the arrays come out columnar."""
 
     def __init__(self, capacity: int = 1024):
         self._buf = np.empty((max(capacity, 16), len(_FIELDS)), np.float64)
@@ -105,6 +106,27 @@ class StageTraceBuilder:
                               n_prefill_tokens, n_decode_tokens, replica,
                               batch_size, score_flops, kv_rw_bytes)
         self._n += 1
+
+    def extend(self, columns) -> None:
+        """Append a block of rows at once. ``columns`` maps each of
+        ``append``'s fields to an array: the cells of ``start_s``, in
+        row-major order, are the block's rows, and every other column
+        broadcasts against its shape (a scalar fills the block). The
+        rows land exactly as that many ``append`` calls would write
+        them; the buffer grows at most once."""
+        shape = np.shape(columns["start_s"])
+        end = self._n + math.prod(shape)
+        if end > len(self._buf):
+            cap = len(self._buf)
+            while cap < end:
+                cap *= 2
+            grown = np.empty((cap, len(_FIELDS)), np.float64)
+            grown[:self._n] = self._buf[:self._n]
+            self._buf = grown
+        block = self._buf[self._n:end].reshape(shape + (len(_FIELDS),))
+        for j, name in enumerate(_FIELDS):
+            block[..., j] = columns[name]
+        self._n = end
 
     def build(self) -> StageTrace:
         cols = {}

@@ -78,6 +78,8 @@ class DeviceStats:
     trace_groups: int = 0
     event_loops: int = 0     # groups driven through the event loop
     replayed: int = 0        # groups served by divergence replay
+    loop_iterations: int = 0  # iterations of those event loops
+    ff_iterations: int = 0   # of which advanced in decode-run steps
     devices: int = 1         # accelerators the dispatch sharded over
     platform: str = ""       # jax platform the program ran on ("tpu")
     device_kind: str = ""    # e.g. "TPU v5 lite"; "cpu" on the host
@@ -237,6 +239,8 @@ def _acquire_results(scenarios: Sequence[Scenario],
             else:
                 results[gi] = run_simulation(cfg)
                 stats.event_loops += 1
+                stats.loop_iterations += results[gi].loop_iterations
+                stats.ff_iterations += results[gi].ff_iterations
             sim_elapsed[gi] = time.perf_counter() - t0
     return results, sim_elapsed
 

@@ -270,6 +270,8 @@ class SweepStats:
     trace_groups: int = 0     # unique simulation traces actually driven
     event_loops: int = 0      # device mode: groups run through the loop
     replayed: int = 0         # device mode: groups shared via divergence
+    loop_iterations: int = 0  # device mode: iterations of those loops
+    ff_iterations: int = 0    # device mode: of which in decode-run steps
     # device mode: what the grid program ran on, so a run on the host
     # CPU never passes for one on the chip
     devices: int = 0
@@ -295,8 +297,10 @@ class SweepStats:
         groups = (f", {self.trace_groups} trace group(s)"
                   if self.mode in ("vectorized", "device") and self.executed
                   else "")
+        ff = 100.0 * self.ff_iterations / max(self.loop_iterations, 1)
         shared = (f" ({self.event_loops} event loop(s), "
-                  f"{self.replayed} replayed)"
+                  f"{self.replayed} replayed, fast-forward {ff:.1f}% of "
+                  f"{self.loop_iterations} iterations)"
                   if self.mode == "device" and self.executed else "")
         if self.device_platform:
             shared += (f", on {self.devices}x {self.device_platform} "
@@ -561,6 +565,8 @@ class SweepRunner:
         stats.trace_groups = dstats.trace_groups
         stats.event_loops = dstats.event_loops
         stats.replayed = dstats.replayed
+        stats.loop_iterations = dstats.loop_iterations
+        stats.ff_iterations = dstats.ff_iterations
         stats.devices = dstats.devices
         stats.device_platform = dstats.platform
         stats.device_kind = dstats.device_kind
