@@ -6,6 +6,25 @@ Forward FLOPs are 2 per multiply-accumulate. Attention has
 ``n_heads`` query heads of ``head_dim`` and ``n_kv_heads`` key/value
 heads; the MLP has two matrices, or three when gated. The output head
 is untied unless ``tie_embeddings``.
+
+Every family module gives the functions below. Two more are optional
+hooks, which a dense model leaves out; ``bench/harness/reference.py``
+asks for them by name:
+
+- ``routed_experts(m) -> None | dict``: the routed experts whose
+  weights a stage reads in part. Keys ``layers`` (layers with routed
+  experts), ``experts`` (E per layer), ``top_k`` (k taken per token)
+  and ``params_per_expert`` (parameters of one expert). Default: none.
+  The reference then reads ``X = layers * params_per_expert *
+  weight_dtype_bytes`` bytes for each distinct expert beyond the k
+  that ``active_param_count`` holds: a stage of T tokens reads
+  ``active_param_count * weight_dtype_bytes + X * (E * (1 - (1 - k/E)
+  ** T) - k)`` bytes of weights.
+- ``kv_copies(m, tp) -> int``: the copies of one token's cache that a
+  replica's ``tp`` ranks hold, a whole number. Default: 1, a cache
+  sharded by head. A latent cache that every rank holds whole gives
+  ``tp``. It multiplies ``kv_bytes_per_token`` in the KV budget (bytes
+  per GPU) and in each stage's KV traffic (bytes).
 """
 
 

@@ -11,6 +11,14 @@ count, MFU and batch averages (the trace), then, as the grid kernel
 does, the roofline of every stage row again, Eq. 1 power, Eq. 2-3
 energy and Eq. 4 carbon, and the record's key, tag and parameters.
 
+A deployment's size arithmetic comes from its family module,
+``bench/families/<family>.py``; two optional hooks there state weight
+and cache traffic that a dense model does not have (see
+``bench/families/dense.py``): ``routed_experts``, whose distinct
+experts per stage grow with the stage's tokens, and ``kv_copies``, the
+copies of one token's cache that a replica's TP ranks hold. A family
+without them is costed exactly as a dense one.
+
 ``Precision`` says in which float types it computes, in three places:
 the event loop's clocks, the grid kernel's roofline and sums, and Eq. 1.
 ``EXACT`` is the reference: IEEE double in all three. ``controls()``
@@ -87,9 +95,10 @@ def hardware() -> Dict[str, dict]:
     return load_json(BENCH / "hardware.json")["devices"]
 
 
-def family(name: str):
-    """The size arithmetic of a model family, ``bench/families/<name>.py``."""
-    path = BENCH / "families" / f"{name}.py"
+def family(name: str, root: Path = None):
+    """The size arithmetic of a model family, ``<root>/<name>.py``;
+    ``root`` is ``bench/families`` unless a test names another."""
+    path = Path(root or BENCH / "families") / f"{name}.py"
     if not path.is_file():
         raise KeyError(f"no reference for model family {name!r} ({path})")
     spec = importlib.util.spec_from_file_location(f"bench_family_{name}",
@@ -148,20 +157,30 @@ def requests(wl: dict):
 
 # ------------------------------------------------------------ roofline ---
 
-def kv_budget(tree: dict, dev: dict) -> int:
-    fam = family(tree["model"]["family"])
+def kv_copies(fam, m: dict, tp: int) -> int:
+    """Copies of one token's cache that a replica's ``tp`` ranks hold:
+    the family's ``kv_copies`` hook, or 1 (head-sharded caches)."""
+    return int(getattr(fam, "kv_copies", lambda m, tp: 1)(m, tp))
+
+
+def kv_budget(tree: dict, dev: dict, fam=None) -> int:
+    fam = fam or family(tree["model"]["family"])
     tp, pp = tree["tp"], tree["pp"]
     w_per_gpu = fam.param_count(tree["model"]) * 2 / (tp * pp)
     room = dev["hbm_bytes"] * 0.9 - w_per_gpu
-    kv_per_gpu = fam.kv_bytes_per_token(tree["model"], 2) / (tp * pp)
+    kv_per_gpu = (fam.kv_bytes_per_token(tree["model"], 2)
+                  * kv_copies(fam, tree["model"], tp) / (tp * pp))
     if room <= 0 or kv_per_gpu <= 0:
         return 0
     return int(room / kv_per_gpu)
 
 
-def roofline_params(tree: dict, dev: dict) -> dict:
+def roofline_params(tree: dict, dev: dict, fam=None) -> dict:
+    """The roofline's parameters. A family with ``routed_experts`` adds
+    ``expert_bytes`` (one expert's weights over every routed layer),
+    ``n_experts`` and ``top_k``; ``weight_bytes`` counts k experts."""
     m, e = tree["model"], tree["execmodel"]
-    fam = family(m["family"])
+    fam = fam or family(m["family"])
     tp, pp = tree["tp"], tree["pp"]
     coll = 0.0
     if tp > 1:   # two ring all-reduces of the activations per layer
@@ -169,6 +188,13 @@ def roofline_params(tree: dict, dev: dict) -> dict:
                  * 2.0 * (tp - 1) / tp) / dev["link_bw"]
     if pp > 1:   # one activation hand-off between pipeline stages
         coll += m["d_model"] * 2 / dev["link_bw"]
+    routed = getattr(fam, "routed_experts", lambda m: None)(m)
+    experts = {} if routed is None else {
+        "expert_bytes": float(routed["layers"] * routed["params_per_expert"]
+                              * e["weight_dtype_bytes"]),
+        "n_experts": float(routed["experts"]),
+        "top_k": float(routed["top_k"]),
+    }
     return {
         "fpt_mlp": float(fam.flops_mlp_per_token(m)),
         "fpt_proj": float(fam.flops_proj_per_token(m)),
@@ -184,7 +210,22 @@ def roofline_params(tree: dict, dev: dict) -> dict:
         "peak": float(dev["peak_flops"] * tp),
         "hbm": float(dev["hbm_bw"] * tp),
         "pp": float(pp),
+        **experts,
     }
+
+
+def weight_traffic(P: dict, tokens):
+    """Weight bytes a stage of ``tokens`` tokens reads. With routed
+    experts, each of E experts of a layer is taken by a token with
+    probability k/E, so the stage reads E(1 - (1 - k/E)^T) distinct
+    experts a layer where ``weight_bytes`` counts k: exact in
+    expectation for balanced routing and independent tokens, and 0
+    extra at T = 1. Without routed experts, ``weight_bytes`` alone."""
+    if "expert_bytes" not in P:
+        return P["weight_bytes"]
+    E, k = P["n_experts"], P["top_k"]
+    return P["weight_bytes"] + P["expert_bytes"] * (
+        E * (1 - (1 - k / E) ** tokens) - k)
 
 
 def roofline(P: dict, npt, ndec, score, kv):
@@ -196,7 +237,7 @@ def roofline(P: dict, npt, ndec, score, kv):
     f_mlp = tokens * P["fpt_mlp"]
     f_attn = tokens * P["fpt_proj"] + score
     flops = (f_mlp + f_attn) / P["pp"]
-    mem = (P["weight_bytes"] + kv + tokens * P["act"]) / P["pp"]
+    mem = (weight_traffic(P, tokens) + kv + tokens * P["act"]) / P["pp"]
     eff = P["eff_max"] * tokens / (tokens + P["eff_half"])
     t_comp = flops / (eff * P["peak"])
     t_mem = mem / P["hbm"]
@@ -265,14 +306,15 @@ def serve(tree: dict, dev: dict, prec: Precision = EXACT) -> Served:
             for i in range(len(arrival))]
     budget = tree["scheduler"]["kv_budget_tokens"]
     if tree["auto_kv_budget"]:
-        budget = kv_budget(tree, dev)
+        budget = kv_budget(tree, dev, fam)
         if budget <= 0:
             raise ValueError(f"{m['name']} does not fit {tree['device']}")
-    params = roofline_params(tree, dev)
+    params = roofline_params(tree, dev, fam)
     P = {k: F(v) for k, v in params.items()}
     coef = int(fam.score_flops_per_token(m, 1))
     win = fam.window(m)
-    kvpt = fam.kv_bytes_per_token(m, tree["execmodel"]["kv_dtype_bytes"])
+    kvpt = (fam.kv_bytes_per_token(m, tree["execmodel"]["kv_dtype_bytes"])
+            * kv_copies(fam, m, tree["tp"]))
     pp = tree["pp"]
 
     reps = [_Replica(tree["scheduler"]["batch_cap"], budget)

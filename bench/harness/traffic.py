@@ -30,6 +30,8 @@ import dataclasses
 import hashlib
 import itertools
 import math
+import types
+import typing
 from typing import Dict, List
 
 #: prompt and generation lengths of the synthetic bucket warm-up: a
@@ -136,17 +138,25 @@ def pad_rows(dep: dict, rows: int) -> int:
     return max(1, math.ceil(rows / (_PAD_ITERS * pp))) * _PAD_ITERS * pp
 
 
-_NESTED = ("attention", "mlp", "moe", "ssm", "rwkv", "zamba")
+def _build(cls, value: dict):
+    """``value`` as an instance of dataclass ``cls``. Every field whose
+    type is a dataclass, or an optional one, is built from its dict in
+    turn, so a nested config that the program adds later is built as
+    its class and not passed on as a dict."""
+    hints = typing.get_type_hints(cls)
+    kw = dict(value)
+    for f in dataclasses.fields(cls):
+        t = hints[f.name]
+        if typing.get_origin(t) in (typing.Union, types.UnionType):
+            t = next(a for a in typing.get_args(t) if a is not type(None))
+        if kw.get(f.name) is not None and dataclasses.is_dataclass(t):
+            kw[f.name] = _build(t, kw[f.name])
+    return cls(**kw)
 
 
 def _model(m: dict):
     from repro.configs import base
-    classes = {"attention": base.AttentionConfig, "mlp": base.MLPConfig,
-               "moe": base.MoEConfig, "ssm": base.SSMConfig,
-               "rwkv": base.RWKVConfig, "zamba": base.ZambaConfig}
-    kw = {k: (classes[k](**v) if k in _NESTED and v is not None else v)
-          for k, v in m.items()}
-    return base.ModelConfig(**kw)
+    return _build(base.ModelConfig, m)
 
 
 def to_program(groups: List[Group]) -> list:
