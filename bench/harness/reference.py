@@ -34,6 +34,7 @@ import hashlib
 import importlib.util
 import json
 from pathlib import Path
+from types import MappingProxyType
 from typing import Callable, Dict, List
 
 import numpy as np
@@ -42,6 +43,32 @@ BENCH = Path(__file__).resolve().parents[1]
 
 #: the record schema version that keys are digested under
 RECORD_SCHEMA = 6
+
+#: the fields of each of the program's model config classes at record
+#: schema 6. A deployment file's ``model`` block is the program's config
+#: in digest form: it writes every one of these, and a field that the
+#: program adds later only where it differs from its default
+#: (``traffic.model_block``). The program's key digest has to follow the
+#: same rule, or its keys part from the reference's hash of the file.
+SCHEMA6_FIELDS = MappingProxyType({
+    "ModelConfig": frozenset((
+        "name", "family", "n_layers", "d_model", "vocab_size", "attention",
+        "mlp", "moe", "ssm", "rwkv", "zamba", "norm", "norm_eps",
+        "tie_embeddings", "max_seq_len", "is_encoder_only", "embed_stub",
+        "dtype")),
+    "AttentionConfig": frozenset((
+        "n_heads", "n_kv_heads", "head_dim", "sliding_window", "rope",
+        "rope_theta", "rope_pct", "causal", "qkv_bias", "kv_repeat")),
+    "MLPConfig": frozenset(("d_ff", "activation", "gated")),
+    "MoEConfig": frozenset((
+        "n_experts", "top_k", "d_expert", "router_jitter", "aux_loss_coef",
+        "n_shared_experts")),
+    "SSMConfig": frozenset((
+        "d_state", "d_conv", "expand", "head_dim", "n_groups")),
+    "RWKVConfig": frozenset(("head_dim", "decay_lora", "mix_lora",
+                             "gate_lora")),
+    "ZambaConfig": frozenset(("shared_attn_every", "shared_attn_copies")),
+})
 
 #: record columns the device program computes from the summed stage
 #: durations alone, all in float64

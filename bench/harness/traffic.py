@@ -34,6 +34,8 @@ import types
 import typing
 from typing import Dict, List
 
+import reference
+
 #: prompt and generation lengths of the synthetic bucket warm-up: a
 #: fixed 64-token request split 32:32 takes exactly 33 iterations when
 #: it is served alone
@@ -157,6 +159,41 @@ def _build(cls, value: dict):
 def _model(m: dict):
     from repro.configs import base
     return _build(base.ModelConfig, m)
+
+
+def _schema6_fields(cls) -> frozenset:
+    """The fields ``cls`` had at record schema 6: those of the nearest
+    class in its MRO that ``reference.SCHEMA6_FIELDS`` names, so a class
+    derived from a schema-6 class keeps its base's; none for a class
+    added later."""
+    for c in cls.__mro__:
+        if c.__name__ in reference.SCHEMA6_FIELDS:
+            return reference.SCHEMA6_FIELDS[c.__name__]
+    return frozenset()
+
+
+def _holds_default(f: dataclasses.Field, value) -> bool:
+    if f.default is not dataclasses.MISSING:
+        return value == f.default
+    if f.default_factory is not dataclasses.MISSING:
+        return value == f.default_factory()
+    return False
+
+
+def model_block(cfg) -> dict:
+    """A program config in the digest form of a deployment file's
+    ``model`` block: ``dataclasses.asdict``, nested configs in turn,
+    less every field outside ``reference.SCHEMA6_FIELDS`` that holds
+    its default."""
+    old = _schema6_fields(type(cfg))
+    out = {}
+    for f in dataclasses.fields(cfg):
+        value = getattr(cfg, f.name)
+        if f.name not in old and _holds_default(f, value):
+            continue
+        out[f.name] = (model_block(value) if dataclasses.is_dataclass(value)
+                       else copy.deepcopy(value))
+    return out
 
 
 def to_program(groups: List[Group]) -> list:
