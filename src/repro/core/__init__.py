@@ -5,9 +5,15 @@ from repro.core.energy import (EnergyReport, operational_energy,
 from repro.core.carbon import (CarbonReport, emissions, emissions_batch,
                                stage_attributed_carbon)
 from repro.core.signals import Signal, aggregate_power
-from repro.core.microgrid import BatteryConfig, MicrogridConfig, simulate, summarize
-from repro.core.cosim import (CosimResult, run_cosim, stages_to_load_signal,
-                              trace_to_load_signal)
+
+# the microgrid scan and the co-simulation bridge import JAX when they
+# load, so they load on first use: the event loop's import chain stays
+# free of JAX (``core.host``)
+_LAZY = {name: "microgrid" for name in (
+    "BatteryConfig", "MicrogridConfig", "simulate", "summarize")}
+_LAZY.update({name: "cosim" for name in (
+    "CosimResult", "run_cosim", "stages_to_load_signal",
+    "trace_to_load_signal")})
 
 __all__ = [
     "DEVICES", "DeviceProfile", "PowerModel", "power",
@@ -19,3 +25,10 @@ __all__ = [
     "CosimResult", "run_cosim", "stages_to_load_signal",
     "trace_to_load_signal",
 ]
+
+
+def __getattr__(name):
+    if name not in _LAZY:
+        raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+    import importlib
+    return getattr(importlib.import_module(f"{__name__}.{_LAZY[name]}"), name)

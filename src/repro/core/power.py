@@ -12,13 +12,13 @@ public TDP / efficiency figures; same functional form).
 
 All functions are vectorized jnp so whole MFU traces (and vmapped
 scenario sweeps) evaluate in one call, on the host CPU (``core.host``).
+They import JAX when they run, so a process that reads only the
+profiles (the event loop) imports none.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Dict
-
-import jax.numpy as jnp
 
 from repro.core.host import on_host
 
@@ -75,6 +75,7 @@ DEVICES["v5p"] = TPU_V5P
 
 def power(mfu, dev: DeviceProfile):
     """Eq. 1, vectorized. mfu in [0, 1] (fraction, not percent)."""
+    import jax.numpy as jnp
     with on_host():
         mfu = jnp.clip(jnp.asarray(mfu, jnp.float32), 0.0, None)
         x = jnp.minimum(mfu, dev.mfu_sat) / dev.mfu_sat
@@ -93,6 +94,7 @@ class PowerModel:
 
     def energy_wh(self, mfu, duration_s, n_devices: int = 1, pue: float = 1.0):
         """Energy in Wh for stages with given MFU and duration (Eq. 3)."""
+        import jax.numpy as jnp
         p = self.power(mfu)
         with on_host():
             return (jnp.sum(p * jnp.asarray(duration_s) / 3600.0)

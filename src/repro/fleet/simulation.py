@@ -13,6 +13,8 @@ results roll up into a fleet-level energy/carbon/latency report.
 Energy semantics: per-site ``energy`` is the paper's Eq. 2-3 active
 (stage-time) energy; the co-sim metrics additionally charge idle power
 for bins where a site sits idle while the fleet is still serving.
+The co-simulation imports JAX, so it is imported where it runs: the
+single-site loop imports this module and no JAX.
 """
 from __future__ import annotations
 
@@ -23,10 +25,8 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from repro.core.carbon import stage_attributed_carbon
-from repro.core.cosim import run_cosim, trace_to_load_signal
 from repro.core.datasets import ci_trace_signal, solar_signal
 from repro.core.energy import EnergyReport, operational_energy_trace
-from repro.core.microgrid import BatteryConfig, MicrogridConfig
 from repro.core.power import DEVICES, PowerModel
 from repro.core.signals import Signal
 from repro.fleet.autoscale import ActiveSetRouter, ReplicaController
@@ -371,6 +371,8 @@ def _site_load_signal(stages: StageTrace, pm: PowerModel, n_devices: int,
     ``n_devices`` scale: each bin draws its per-device power times the
     devices actually powered then (cold replicas draw nothing, warm
     spares draw idle)."""
+    from repro.core.cosim import trace_to_load_signal
+
     n_bins = max(1, int(math.ceil(t_end_s / resolution_s)))
     times = np.arange(n_bins) * resolution_s
     if device_signal is not None:
@@ -493,6 +495,9 @@ def run_fleet_simulation(cfg: FleetConfig,
     """``probe`` (``repro.obs.Probe``, optional) observes routing,
     stages, autoscaling and the per-site rollup; it never feeds back
     into the simulation (probe-off == probe-on, bitwise)."""
+    from repro.core.cosim import run_cosim
+    from repro.core.microgrid import BatteryConfig, MicrogridConfig
+
     requests = generate(cfg.workload)
     wl = cfg.workload
     defer_slack = (wl.deferrable_deadline_s

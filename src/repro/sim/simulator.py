@@ -8,7 +8,9 @@ granularity the paper's Eq. 2-3 energy accounting consumes.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional
+import os
+import time
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -144,6 +146,18 @@ def run_simulation(cfg: SimConfig, max_sim_s: float = 10_000_000.0,
     return SimResult(stages=site.stage_log(), requests=requests, cfg=cfg,
                      loop_iterations=site.loop_iterations,
                      ff_iterations=site.ff_iterations)
+
+
+def run_simulation_in_child(cfg: SimConfig) -> Tuple[SimResult, float, int]:
+    """``run_simulation`` as a host pool task (``repro.sweep.device``):
+    the result less its config, which the caller holds; the loop's own
+    elapsed seconds; and the pid that ran it. It lives beside the loop,
+    not in ``repro.sweep``, so a child imports the loop and no JAX."""
+    t0 = time.perf_counter()
+    res = run_simulation(cfg)
+    elapsed = time.perf_counter() - t0
+    res.cfg = None
+    return res, elapsed, os.getpid()
 
 
 def energy_report(res: SimResult, pue: float = 1.2):

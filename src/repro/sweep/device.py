@@ -18,6 +18,20 @@ for groups the conservative predicate rejects. Record assembly reuses
 ``runner.single_site_metrics``, so device-mode records carry exactly
 the event-loop columns.
 
+The event loops of a sweep's groups do not depend on each other, so
+they run side by side in a host process pool that the module keeps for
+the life of the process (``runner.host_pool``'s spawn context and CPU-only
+initializer, one process short of the usable cores, started on the
+first sweep that needs it, shut down at exit). It engages only where
+it can pay for its round trip: at least two event-loop groups holding
+``POOL_MIN_REQUESTS`` requests together, at least two usable cores,
+and a process that may have children (not a daemon); otherwise the
+loops run in this process as before. Replay and fleet scenarios always
+stay here. A child imports the event loop and no JAX (``core.host``),
+and returns the same ``SimResult`` the loop gives here, less the config
+the parent already holds, so records are bitwise those of the
+in-process path (``tests/test_acquire_pool.py``).
+
 **Tolerance contract** (see README): numpy modes are bit-identical to
 the event loop; device mode is NOT. (a) The roofline and the Eq. 2-4
 arithmetic run in float64, which a TPU emulates: ~1e-14 relative per
@@ -34,9 +48,13 @@ the host-side trace and stay bitwise.
 """
 from __future__ import annotations
 
+import atexit
 import dataclasses
+import multiprocessing
 import os
 import time
+from concurrent.futures import ProcessPoolExecutor
+from concurrent.futures.process import BrokenProcessPool
 from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -85,6 +103,8 @@ class DeviceStats:
     device_kind: str = ""    # e.g. "TPU v5 lite"; "cpu" on the host
     bucket: Tuple[int, int, int] = (0, 0, 0)   # padded (G, S, K)
     compiles: int = 0        # executables built or loaded in the dispatch
+    pooled: int = 0          # event loops run in host pool children
+    pool_workers: int = 0    # pool processes that served them
 
 
 def _next_pow2(n: int) -> int:
@@ -212,36 +232,113 @@ def _pmap_program(n_dev: int):
     return prog
 
 
+#: event-loop requests that a sweep's groups must hold together before
+#: their loops go to the host pool. A warm pool's round trip (submit,
+#: pickle, wake a child, pickle the ``SimResult`` back) is ~1.3 ms: two
+#: groups of 8 requests took 2.39 ms pooled against 2.12 ms in process
+#: (x86 host, 8 cores). The loop costs ~0.18 ms a request on the chip's
+#: host (9 x 512 requests in 0.83 s), so two groups break even near
+#: 2 x 1.3 / 0.18 ~ 15 requests. The threshold sits well above that,
+#: because a process pays once for starting the children (~0.7 s on the
+#: chip's host): at 512 requests a sweep saves ~45 ms, so only grids
+#: that save tens of milliseconds a sweep start processes, and the small
+#: grids of tests and self-checks keep the in-process path.
+POOL_MIN_REQUESTS = 512
+
+_POOL: Optional[ProcessPoolExecutor] = None
+
+
+def _usable_cores() -> int:
+    return len(os.sched_getaffinity(0))
+
+
+def _pool_engages(loop_cfgs: Sequence) -> bool:
+    """Whether the event loops of ``loop_cfgs`` go to the host pool: the
+    rule reads only the grid, the machine and the process."""
+    return (len(loop_cfgs) >= 2 and _usable_cores() >= 2
+            and not multiprocessing.current_process().daemon
+            and sum(c.workload.n_requests for c in loop_cfgs)
+            >= POOL_MIN_REQUESTS)
+
+
+def _loop_pool() -> ProcessPoolExecutor:
+    """The module's host pool, started on first use (its children spawn
+    as tasks need them) and shut down at exit."""
+    global _POOL
+    if _POOL is None:
+        from repro.sweep.runner import host_pool
+        _POOL = host_pool(_usable_cores() - 1)
+        atexit.register(_POOL.shutdown)
+    return _POOL
+
+
+def _drop_pool() -> None:
+    global _POOL
+    pool, _POOL = _POOL, None
+    if pool is not None:
+        pool.shutdown(wait=False, cancel_futures=True)
+
+
 def _acquire_results(scenarios: Sequence[Scenario],
                      single: List[List[int]], stats: DeviceStats
                      ) -> Tuple[list, List[float]]:
     """One SimResult per single-site trace group: divergence-shared
     families replay one composition schedule per config; everything
-    else runs the event loop."""
+    else runs the event loop, in the host pool where it engages
+    (largest groups first), else here."""
     from repro.sim import run_simulation
+    from repro.sim.simulator import run_simulation_in_child
 
+    cfgs = [scenarios[g[0]].cfg for g in single]
     fams: Dict[str, List[int]] = {}
-    for gi, g in enumerate(single):
-        blob = divergence.family_blob(scenarios[g[0]].cfg)
-        fams.setdefault(blob, []).append(gi)
+    for gi, cfg in enumerate(cfgs):
+        fams.setdefault(divergence.family_blob(cfg), []).append(gi)
+
+    replays: List[int] = []
+    loops: List[int] = []
+    for members in fams.values():
+        shared = (len(members) > 1 and divergence.trace_shareable(
+            [cfgs[gi] for gi in members])[0])
+        (replays if shared else loops).extend(members)
 
     results: list = [None] * len(single)
     sim_elapsed = [0.0] * len(single)
-    for members in fams.values():
-        cfgs = [scenarios[single[gi][0]].cfg for gi in members]
-        shared = (len(members) > 1
-                  and divergence.trace_shareable(cfgs)[0])
-        for gi, cfg in zip(members, cfgs):
-            t0 = time.perf_counter()
-            if shared:
-                results[gi] = divergence.replay_result(cfg)
-                stats.replayed += 1
-            else:
-                results[gi] = run_simulation(cfg)
-                stats.event_loops += 1
-                stats.loop_iterations += results[gi].loop_iterations
-                stats.ff_iterations += results[gi].ff_iterations
-            sim_elapsed[gi] = time.perf_counter() - t0
+
+    def here(gi, acquire):
+        t0 = time.perf_counter()
+        results[gi] = acquire(cfgs[gi])
+        sim_elapsed[gi] = time.perf_counter() - t0
+
+    futures = {}
+    try:
+        if _pool_engages([cfgs[gi] for gi in loops]):
+            pool = _loop_pool()
+            for gi in sorted(loops, key=lambda gi: (
+                    -cfgs[gi].workload.n_requests, gi)):
+                futures[gi] = pool.submit(run_simulation_in_child, cfgs[gi])
+        # the parent's own share runs while the children work
+        for gi in replays:
+            here(gi, divergence.replay_result)
+        for gi in loops:
+            if gi not in futures:
+                here(gi, run_simulation)
+        if futures:
+            pids = set()
+            with PROFILER.span("device.acquire_pool"):
+                for gi, fut in futures.items():
+                    results[gi], sim_elapsed[gi], pid = fut.result()
+                    results[gi].cfg = cfgs[gi]
+                    pids.add(pid)
+            stats.pooled = len(futures)
+            stats.pool_workers = len(pids)
+    except BrokenProcessPool:
+        _drop_pool()            # a child died: the next sweep starts anew
+        raise
+    stats.replayed = len(replays)
+    stats.event_loops = len(loops)
+    for gi in loops:
+        stats.loop_iterations += results[gi].loop_iterations
+        stats.ff_iterations += results[gi].ff_iterations
     return results, sim_elapsed
 
 

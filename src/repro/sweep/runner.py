@@ -29,7 +29,6 @@ from __future__ import annotations
 
 import dataclasses
 import multiprocessing
-import os
 import time
 from concurrent.futures import ProcessPoolExecutor
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -37,6 +36,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.carbon import emissions
+from repro.core.host import use_cpu_only
 from repro.core.power import DEVICES
 from repro.fleet.config import FleetConfig
 from repro.obs.spans import PROFILER
@@ -272,6 +272,8 @@ class SweepStats:
     replayed: int = 0         # device mode: groups shared via divergence
     loop_iterations: int = 0  # device mode: iterations of those loops
     ff_iterations: int = 0    # device mode: of which in decode-run steps
+    pooled: int = 0           # device mode: event loops run in pool children
+    pool_workers: int = 0     # device mode: pool processes that served them
     # device mode: what the grid program ran on, so a run on the host
     # CPU never passes for one on the chip
     devices: int = 0
@@ -298,7 +300,8 @@ class SweepStats:
                   if self.mode in ("vectorized", "device") and self.executed
                   else "")
         ff = 100.0 * self.ff_iterations / max(self.loop_iterations, 1)
-        shared = (f" ({self.event_loops} event loop(s), "
+        shared = (f" ({self.event_loops} event loop(s), {self.pooled} "
+                  f"pooled on {self.pool_workers} process(es), "
                   f"{self.replayed} replayed, fast-forward {ff:.1f}% of "
                   f"{self.loop_iterations} iterations)"
                   if self.mode == "device" and self.executed else "")
@@ -567,22 +570,13 @@ class SweepRunner:
         stats.replayed = dstats.replayed
         stats.loop_iterations = dstats.loop_iterations
         stats.ff_iterations = dstats.ff_iterations
+        stats.pooled = dstats.pooled
+        stats.pool_workers = dstats.pool_workers
         stats.devices = dstats.devices
         stats.device_platform = dstats.platform
         stats.device_kind = dstats.device_kind
         stats.compiles = dstats.compiles
         return fresh
-
-
-def use_cpu_only() -> None:
-    """Start this process's JAX with the CPU platform alone, for a
-    process that computes the host path only (pool children, vectorized
-    remote workers). Creating a backend opens every platform JAX may
-    use, so pinning a device later would still take the chip from the
-    process that holds it."""
-    import jax
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    jax.config.update("jax_platforms", "cpu")
 
 
 def host_pool(n: int) -> ProcessPoolExecutor:

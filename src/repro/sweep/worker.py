@@ -223,7 +223,7 @@ def main(argv=None) -> int:
         crash_after = int(os.environ[remote.ENV_CRASH_AFTER_GROUPS])
 
     if args.mode == "vectorized":
-        from repro.sweep.runner import use_cpu_only
+        from repro.core.host import use_cpu_only
         use_cpu_only()
     elif args.mode == "device":
         try:
