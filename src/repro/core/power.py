@@ -73,14 +73,22 @@ DEVICES["v5e"] = TPU_V5E
 DEVICES["v5p"] = TPU_V5P
 
 
+def eq1_power(mfu, p_idle, p_delta, mfu_sat, gamma):
+    """Eq. 1 in float32, elementwise over ``mfu``, with ``p_delta =
+    p_max_inst - p_idle``. The one expression of the curve: ``power``
+    calls it on the host with a profile's floats, and the device sweep's
+    kernel inside its program with float32 constants."""
+    import jax.numpy as jnp
+    mfu = jnp.clip(jnp.asarray(mfu, jnp.float32), 0.0, None)
+    x = jnp.minimum(mfu, mfu_sat) / mfu_sat
+    return p_idle + p_delta * jnp.power(x, gamma)
+
+
 def power(mfu, dev: DeviceProfile):
     """Eq. 1, vectorized. mfu in [0, 1] (fraction, not percent)."""
-    import jax.numpy as jnp
     with on_host():
-        mfu = jnp.clip(jnp.asarray(mfu, jnp.float32), 0.0, None)
-        x = jnp.minimum(mfu, dev.mfu_sat) / dev.mfu_sat
-        return dev.p_idle + (dev.p_max_inst - dev.p_idle) * jnp.power(
-            x, dev.gamma)
+        return eq1_power(mfu, dev.p_idle, dev.p_max_inst - dev.p_idle,
+                         dev.mfu_sat, dev.gamma)
 
 
 class PowerModel:

@@ -13,8 +13,6 @@ classified against the repo's named tolerance contracts:
 * ``host-bitwise`` (rtol 0) — the contract identical cells satisfy;
 * ``DEVICE_MODE_RTOL`` — batched device-grid vs host numerics
   (``repro.sweep.device``);
-* ``JAX_BACKEND_RTOL`` — jax vs numpy roofline backends
-  (``repro.sim.execmodel``);
 * ``DAY_FLUID_RTOL`` — fluid vs exact day epochs
   (``repro.sweep.scenarios``);
 * ``regression`` — outside every named contract: a real drift.
@@ -83,12 +81,10 @@ def _phase_rank(column: str) -> Tuple[int, str]:
 def tolerance_contracts() -> List[Tuple[str, float]]:
     """The named tolerance ladder, tightest first. Imported lazily so
     ``repro.obs`` never drags the sweep/sim layers in at import time."""
-    from repro.sim.execmodel import JAX_BACKEND_RTOL
     from repro.sweep.device import DEVICE_MODE_RTOL
     from repro.sweep.scenarios import DAY_FLUID_RTOL
     return [("host-bitwise", 0.0),
             ("DEVICE_MODE_RTOL", DEVICE_MODE_RTOL),
-            ("JAX_BACKEND_RTOL", JAX_BACKEND_RTOL),
             ("DAY_FLUID_RTOL", DAY_FLUID_RTOL),
             ("regression", math.inf)]
 

@@ -3,7 +3,7 @@ from repro.sim.execmodel import (ExecModelConfig, ExecutionModel, StageBatch,
                                  cached_execution_model)
 from repro.sim.requests import Request, WorkloadConfig, generate
 from repro.sim.scheduler import ReplicaScheduler, SchedulerConfig
-from repro.sim.simulator import (SimConfig, SimResult, StageLog, energy_report,
+from repro.sim.simulator import (SimConfig, SimResult, energy_report,
                                  run_simulation)
 from repro.sim.trace import StageTrace, StageTraceBuilder
 from repro.sim.defaults import INTEGRATION_DEFAULT, PAPER_DEFAULT, PAPER_PUE
@@ -12,17 +12,8 @@ __all__ = [
     "ExecModelConfig", "ExecutionModel", "StageBatch", "StageCost",
     "StageCostBatch", "cached_execution_model",
     "Request", "WorkloadConfig", "generate",
-    "ReplicaScheduler", "RoundRobinRouter", "SchedulerConfig",
-    "SimConfig", "SimResult", "StageLog", "energy_report", "run_simulation",
+    "ReplicaScheduler", "SchedulerConfig",
+    "SimConfig", "SimResult", "energy_report", "run_simulation",
     "StageTrace", "StageTraceBuilder",
     "INTEGRATION_DEFAULT", "PAPER_DEFAULT", "PAPER_PUE",
 ]
-
-
-def __getattr__(name):
-    # moved to the routing layer; lazy so repro.sim <-> repro.fleet
-    # imports never cycle at module load
-    if name == "RoundRobinRouter":
-        from repro.fleet.routing import RoundRobinRouter
-        return RoundRobinRouter
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -14,14 +14,17 @@ near MFU 0.45 at 5-8 QPS, reproducing the paper's Fig. 1. On TPU the
 same form is calibrated against the dry-run's compiled cost analysis
 (`calibrate_from_dryrun`).
 
-Array-native core: a stage's composition reduces to four aggregates —
-summed prefill tokens, decode count, score FLOPs, KV read/write bytes
-(``StageBatch``) — and the roofline over those aggregates is a pure
-elementwise kernel (``stage_cost_batch``) that evaluates ONE stage or a
-whole trace of stages in a single numpy pass (optionally ``jax.jit``).
-The scalar ``stage_cost`` is a thin length-1 view over the batched
-kernel, so scalar (event-loop) and batched (sweep replay) paths are
-bit-identical by construction.
+One stage-cost model: a stage's composition reduces to four
+aggregates — summed prefill tokens, decode count, score FLOPs, KV
+read/write bytes (``StageBatch``). Each sequence's share of the last two
+has one definition (``prefill_terms`` for a chunk at an offset,
+``decode_terms`` for a decode at a context), elementwise over floats or
+arrays. The roofline over the aggregates has one body
+(``_live_roofline``); ``_roofline`` is its masked form over stages that
+may be empty, which ``stage_cost_batch`` runs with numpy and the device
+kernel with ``jax.numpy``. The event loop's ``stage_cost_scalar`` and
+``decode_run``, trace replay and the device program all go through
+these, so their costs are bit-identical by construction.
 
 All per-model constants (active parameter count, KV bytes/token,
 per-token FLOP totals, score coefficients) are computed once at
@@ -37,7 +40,6 @@ from typing import Optional, Sequence
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.core.host import on_host
 from repro.core.power import DEVICES, DeviceProfile
 
 
@@ -108,16 +110,6 @@ class StageCostBatch:
     def __len__(self) -> int:
         return len(self.t_total)
 
-    def row(self, i: int = 0) -> StageCost:
-        return StageCost(
-            t_total=float(self.t_total[i]),
-            t_compute=float(self.t_compute[i]),
-            t_memory=float(self.t_memory[i]),
-            t_collective=float(self.t_collective[i]),
-            flops_mlp=float(self.flops_mlp[i]),
-            flops_attn=float(self.flops_attn[i]),
-            mfu=float(self.mfu[i]))
-
 
 @dataclasses.dataclass(frozen=True)
 class _Params:
@@ -143,49 +135,11 @@ class _Params:
 #: which reconstructs ``_Params(*row)`` per trace group inside vmap)
 PARAMS_FIELDS = tuple(f.name for f in dataclasses.fields(_Params))
 
-#: relative tolerance for ``stage_cost_batch(backend="jax")`` against
-#: the ``"numpy"`` reference: the jitted kernel runs in float32 on
-#: default jax builds (eps ~1.2e-7) and the roofline chains ~6
-#: elementwise ops, so the observed divergence is a few f32 ulps;
-#: 1e-5 leaves roughly two decades of margin (pinned per paper model
-#: by tests/test_device_mode.py).
-JAX_BACKEND_RTOL = 1e-5
-
-
-def _roofline(prefill_tokens, decode_count, score_flops, kv_rw_bytes,
-              p, xp=np):
-    """The three-term roofline, elementwise over stages. ``xp`` is
-    ``numpy`` (default) or ``jax.numpy`` — same ops either way."""
-    tokens = prefill_tokens + decode_count
-    live = tokens > 0
-    safe_tokens = xp.where(live, tokens, 1.0)
-
-    f_mlp = tokens * p.fpt_mlp
-    f_attn = tokens * p.fpt_proj + score_flops
-    flops_st = (f_mlp + f_attn) / p.pp
-    mem_st = (p.weight_bytes + kv_rw_bytes
-              + tokens * p.act_bytes_per_token) / p.pp
-
-    eff = p.eff_max * safe_tokens / (safe_tokens + p.eff_half_tokens)
-    t_comp = flops_st / (eff * p.peak_chips)
-    t_mem = mem_st / p.hbm_chips
-    t_coll = tokens * p.coll_s_per_token
-    t = (xp.maximum(t_comp, t_mem) + p.coll_scale * t_coll
-         + p.overhead_s)
-    mfu = flops_st / (p.peak_chips * xp.where(live, t, 1.0))
-
-    zero = xp.zeros_like(tokens)
-    out = []
-    for v in (t, t_comp, t_mem, t_coll, f_mlp / p.pp, f_attn / p.pp, mfu):
-        out.append(xp.where(live, v, zero))
-    return tuple(out)
-
-
 def _live_roofline(tokens, f_score, kv_rw, p, maximum):
-    """The roofline of stages with ``tokens > 0`` in the scalar path's
-    operation order: ``tokens`` is a float, ``f_score`` and ``kv_rw``
-    floats (with ``maximum=max``) or arrays over stages (with
-    ``np.maximum``). Returns ``StageCost``'s fields in order."""
+    """The roofline of stages with ``tokens > 0``: ``tokens``,
+    ``f_score`` and ``kv_rw`` are floats (with ``maximum=max``) or
+    arrays over stages (with ``np.maximum`` / ``jnp.maximum``). Returns
+    ``StageCost``'s fields in order."""
     f_mlp = tokens * p.fpt_mlp
     f_attn = tokens * p.fpt_proj + f_score
     flops_st = (f_mlp + f_attn) / p.pp
@@ -198,6 +152,19 @@ def _live_roofline(tokens, f_score, kv_rw, p, maximum):
     t = maximum(t_comp, t_mem) + p.coll_scale * t_coll + p.overhead_s
     return (t, t_comp, t_mem, t_coll, f_mlp / p.pp, f_attn / p.pp,
             flops_st / (p.peak_chips * t))
+
+
+def _roofline(prefill_tokens, decode_count, score_flops, kv_rw_bytes,
+              p, xp=np):
+    """``_live_roofline`` over stages that may be empty: an empty stage
+    is evaluated at 1 token and every one of its outputs set to 0.
+    ``xp`` is ``numpy`` (default) or ``jax.numpy``."""
+    tokens = prefill_tokens + decode_count
+    live = tokens > 0
+    out = _live_roofline(xp.where(live, tokens, 1.0), score_flops,
+                         kv_rw_bytes, p, xp.maximum)
+    zero = xp.zeros_like(tokens)
+    return tuple(xp.where(live, v, zero) for v in out)
 
 
 class ExecutionModel:
@@ -248,11 +215,6 @@ class ExecutionModel:
             peak_chips=float(device.peak_flops * chips),
             hbm_chips=float(device.hbm_bw * chips),
             pp=float(pp))
-        self._jax_kernel = None
-
-    def _eff(self, tokens: float) -> float:
-        c = self.cfg
-        return c.eff_max * tokens / (tokens + c.eff_half_tokens)
 
     def params_vector(self) -> np.ndarray:
         """The resolved roofline parameters as a flat float64 vector in
@@ -290,126 +252,67 @@ class ExecutionModel:
         return (self.score_coef * np.minimum(ctx, self.sliding_window)
                 + self.score_const)
 
-    def aggregate(self, prefill_lens: Sequence[int],
-                  decode_ctxs: Sequence[int],
-                  prefill_offsets: Optional[Sequence[int]] = None
-                  ) -> StageBatch:
-        """Reduce ONE stage's composition to its StageBatch aggregates
-        (length-1 arrays).
-
-        prefill_lens: prompt (chunk) token counts prefilled this stage.
-        decode_ctxs: context lengths of sequences generating one token.
-        prefill_offsets: tokens of each prompt ALREADY prefilled by
-        earlier chunks (Sarathi chunking); 0/None = fresh prefill. A
-        chunk at offset o attends over the o previously-prefilled
-        context tokens, so it re-reads their KV (the cross-chunk read
-        term) and its score FLOPs see an average context of o + L/2
-        instead of L/2.
-        """
-        plens = np.asarray(prefill_lens, np.float64)
-        ctxs = np.asarray(decode_ctxs, np.float64)
-        if prefill_offsets is None:
-            offs = np.zeros_like(plens)
-        else:
-            offs = np.asarray(prefill_offsets, np.float64)
-
-        npt = float(np.sum(plens))
-        nd = float(len(ctxs))
-
-        # causal prefill: average context = offset + L/2
-        avg_ctx = np.maximum(offs + np.floor(plens / 2.0), 1.0)
-        f_score = (float(np.sum(plens * self._score_per_token(avg_ctx)))
-                   + float(np.sum(self._score_per_token(ctxs))))
-
+    def prefill_terms(self, plens, offs):
+        """Score FLOPs and KV bytes of prefill chunks of ``plens`` tokens
+        at offsets ``offs`` (tokens of the prompt already prefilled by
+        earlier chunks; 0 for a fresh prefill), elementwise. Causal
+        prefill sees an average context of ``o + floor(L/2)``; the chunk
+        writes its own K/V and re-reads the prior context's
+        (window-bounded)."""
         kvpt = self.kv_bytes_per_token
-        w = self.sliding_window
-        # prefill writes its chunk's K/V and re-reads the already-
-        # prefilled context (bounded by the attention window)
-        kv_pre = np.sum(plens * kvpt + np.minimum(offs, w) * kvpt)
-        # decode reads the cache (window-bounded) + writes one token
-        kv_dec = np.sum(np.minimum(ctxs, w) * kvpt + kvpt)
-        kv_rw = float(kv_pre + kv_dec)
+        avg_ctx = np.maximum(offs + np.floor(plens / 2.0), 1.0)
+        return (plens * self._score_per_token(avg_ctx),
+                plens * kvpt + np.minimum(offs, self.sliding_window) * kvpt)
 
-        return StageBatch(prefill_tokens=np.array([npt]),
-                          decode_count=np.array([nd]),
-                          score_flops=np.array([f_score]),
-                          kv_rw_bytes=np.array([kv_rw]))
+    def decode_terms(self, ctxs):
+        """Score FLOPs and KV bytes of decodes at contexts ``ctxs``,
+        elementwise: each reads its cache (window-bounded) and writes
+        one token."""
+        kvpt = self.kv_bytes_per_token
+        return (self._score_per_token(ctxs),
+                np.minimum(ctxs, self.sliding_window) * kvpt + kvpt)
 
-    def stage_cost_batch(self, batch: StageBatch,
-                         backend: str = "numpy") -> StageCostBatch:
-        """Evaluate the roofline over N stages in one array pass.
-
-        ``backend="numpy"`` (default) is the reference path — bit-
-        identical to the scalar ``stage_cost``. ``backend="jax"`` jits
-        the same kernel on the host CPU (float32, so outputs are close
-        but not bit-equal; ``JAX_BACKEND_RTOL`` bounds them).
-        """
-        args = (np.asarray(batch.prefill_tokens, np.float64),
-                np.asarray(batch.decode_count, np.float64),
-                np.asarray(batch.score_flops, np.float64),
-                np.asarray(batch.kv_rw_bytes, np.float64))
-        if backend == "numpy":
-            return StageCostBatch(*_roofline(*args, self._params, np))
-        if backend == "jax":
-            if self._jax_kernel is None:
-                import jax
-                import jax.numpy as jnp
-                p = self._params
-                self._jax_kernel = jax.jit(
-                    lambda npt, nd, sc, kv: _roofline(npt, nd, sc, kv,
-                                                      p, jnp))
-            with on_host():
-                out = self._jax_kernel(*args)
-            return StageCostBatch(*(np.asarray(v) for v in out))
-        raise ValueError(f"unknown backend {backend!r}")
-
-    def stage_cost(self, prefill_lens: Sequence[int],
-                   decode_ctxs: Sequence[int],
-                   prefill_offsets: Optional[Sequence[int]] = None
-                   ) -> StageCost:
-        """Cost of ONE batch stage (= one scheduler iteration on one
-        pipeline stage's share of layers) — a length-1 view over
-        ``stage_cost_batch``."""
-        batch = self.aggregate(prefill_lens, decode_ctxs, prefill_offsets)
-        return self.stage_cost_batch(batch).row(0)
+    def stage_cost_batch(self, batch: StageBatch) -> StageCostBatch:
+        """Evaluate the roofline over N stages in one numpy pass —
+        bit-identical, row for row, to ``stage_cost_scalar``."""
+        return StageCostBatch(*_roofline(
+            np.asarray(batch.prefill_tokens, np.float64),
+            np.asarray(batch.decode_count, np.float64),
+            np.asarray(batch.score_flops, np.float64),
+            np.asarray(batch.kv_rw_bytes, np.float64), self._params, np))
 
     def stage_cost_scalar(self, prefill_lens: Sequence[int],
                           decode_ctxs: Sequence[int],
                           prefill_offsets: Optional[Sequence[int]] = None):
-        """One stage's cost without the length-1 array round-trip:
-        ``aggregate`` + ``stage_cost_batch().row(0)`` spend most of
-        their time wrapping four scalars into arrays and dispatching
-        elementwise kernels over them — pure overhead on the event
-        loop's hot path, where a day-scale exact epoch evaluates
-        hundreds of thousands of single stages.
+        """Cost of ONE batch stage (= one scheduler iteration on one
+        pipeline stage's share of layers), on the event loop's hot
+        path: the composition terms reduced with numpy's pairwise
+        ``.sum()``, the roofline on Python floats (no length-1 arrays).
 
-        Bit-identical to the batched path by construction: the batch-
-        composition reductions keep numpy's pairwise summation (same
-        expressions, ``.sum()`` method instead of the ``np.sum``
-        wrapper), and the roofline runs the same IEEE-double operation
-        sequence on Python floats. Pinned by tests.
+        prefill_lens: prompt (chunk) token counts prefilled this stage.
+        decode_ctxs: context lengths of sequences generating one token.
+        prefill_offsets: tokens of each prompt already prefilled by
+        earlier chunks (Sarathi chunking); None = fresh prefills.
 
         Returns ``(StageCost, prefill_tokens, decode_count,
         score_flops, kv_rw_bytes)`` — the cost plus the stage's
         StageBatch aggregates as plain floats (what the trace logs).
         """
         ctxs = np.asarray(decode_ctxs, np.float64)
-        kvpt = self.kv_bytes_per_token
-        w = self.sliding_window
         nd = float(len(ctxs))
         if len(prefill_lens):
             plens = np.asarray(prefill_lens, np.float64)
             offs = (np.zeros_like(plens) if prefill_offsets is None
                     else np.asarray(prefill_offsets, np.float64))
             npt = float(plens.sum())
-            avg_ctx = np.maximum(offs + np.floor(plens / 2.0), 1.0)
-            f_pre = float((plens * self._score_per_token(avg_ctx)).sum())
-            kv_pre = (plens * kvpt + np.minimum(offs, w) * kvpt).sum()
+            score, kv = self.prefill_terms(plens, offs)
+            f_pre = float(score.sum())
+            kv_pre = kv.sum()
         else:
             npt = f_pre = kv_pre = 0.0      # the empty sums
-        f_score = f_pre + float(self._score_per_token(ctxs).sum())
-        kv_dec = (np.minimum(ctxs, w) * kvpt + kvpt).sum()
-        kv_rw = float(kv_pre + kv_dec)
+        score, kv = self.decode_terms(ctxs)
+        f_score = f_pre + float(score.sum())
+        kv_rw = float(kv_pre + kv.sum())
 
         tokens = npt + nd
         if tokens > 0:
@@ -426,10 +329,10 @@ class ExecutionModel:
         the run.
 
         Bit-identical to ``k`` calls of ``stage_cost_scalar`` by
-        construction: each stage's aggregates are the same expressions
-        reduced over one contiguous row of a ``(k, n)`` context grid
-        (the same pairwise sum as the 1-D one), and the roofline is the
-        scalar path's own, with the stage-invariant terms as floats.
+        construction: each stage's ``decode_terms`` are reduced over one
+        contiguous row of a ``(k, n)`` context grid (the same pairwise
+        sum as the 1-D one), and the roofline is the scalar path's own,
+        with the stage-invariant terms as floats.
 
         Returns ``(t_total, flops_mlp, flops_attn, mfu, score_flops,
         kv_rw_bytes)``; ``flops_mlp`` is one float, the same for every
@@ -437,10 +340,9 @@ class ExecutionModel:
         """
         grid = (np.asarray(decode_ctxs, np.float64)
                 + np.arange(k, dtype=np.float64)[:, None])
-        f_score = 0.0 + self._score_per_token(grid).sum(axis=1)
-        kvpt = self.kv_bytes_per_token
-        kv_rw = 0.0 + (np.minimum(grid, self.sliding_window) * kvpt
-                       + kvpt).sum(axis=1)
+        score, kv = self.decode_terms(grid)
+        f_score = 0.0 + score.sum(axis=1)
+        kv_rw = 0.0 + kv.sum(axis=1)
         t, _, _, _, f_mlp, f_attn, mfu = _live_roofline(
             0.0 + float(len(decode_ctxs)), f_score, kv_rw, self._params,
             np.maximum)

@@ -139,13 +139,3 @@ class ReplicaScheduler:
             self.running.remove(r)
             self.kv_tokens -= r.prefill_tokens + r.decoded
         return done
-
-
-def __getattr__(name):
-    # RoundRobinRouter moved to the routing layer (repro.fleet.routing);
-    # resolved lazily here to keep the historical import path working
-    # without a circular import at module load.
-    if name == "RoundRobinRouter":
-        from repro.fleet.routing import RoundRobinRouter
-        return RoundRobinRouter
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -21,10 +21,6 @@ from repro.sim.requests import Request, WorkloadConfig, generate
 from repro.sim.scheduler import SchedulerConfig
 from repro.sim.trace import StageTrace
 
-# the stage log became the array-native StageTrace (repro.sim.trace);
-# the historical name keeps working for existing callers
-StageLog = StageTrace
-
 
 def kv_budget_tokens(model: ModelConfig, device: DeviceProfile, tp: int,
                      pp: int, mem_frac: float = 0.9,

@@ -4,7 +4,8 @@ One ``jax.jit`` + ``vmap`` program evaluates EVERY trace group's
 post-simulation passes at once: the groups' ``StageTrace`` composition
 columns are zero-padded and ragged-stacked into one ``(G, S)`` tensor
 set, and the batched roofline (the same ``_roofline`` kernel
-``stage_cost_batch`` runs), the Eq. 1-3 power/energy reductions and
+``stage_cost_batch`` runs), Eq. 1 (``core.power.eq1_power``, the
+expression the host's ``power`` evaluates), the Eq. 2-3 reductions and
 the Eq. 4 emissions — including the per-group scenario fan-out over
 the ``pue`` / ``grid_ci`` axes as a stacked ``(G, K)`` axis — compile
 into a single device dispatch for the whole grid, instead of one numpy
@@ -62,7 +63,7 @@ import numpy as np
 
 from repro.core.carbon import reports_from_arrays
 from repro.core.energy import reports_from_sums
-from repro.core.power import DEVICES
+from repro.core.power import DEVICES, eq1_power
 from repro.fleet.config import FleetConfig
 from repro.obs.spans import PROFILER
 from repro.sim.execmodel import (PARAMS_FIELDS, _Params, _roofline,
@@ -128,12 +129,10 @@ def _group_kernel(comp_pre, comp_dec, comp_score, comp_kv,
     dur_s, mfu = t[0], t[6]
     live = (comp_pre + comp_dec) > 0
 
-    # Eq. 1 in float32, mirroring core.power.power() op for op; the
-    # (p_max - p_idle) delta is precomputed host-side in f64 (powerp[4])
-    # exactly as the eager path subtracts python floats
-    mfu32 = jnp.clip(jnp.asarray(mfu, jnp.float32), 0.0, None)
-    x = jnp.minimum(mfu32, powerp[2]) / powerp[2]
-    pw = powerp[0] + powerp[4] * jnp.power(x, powerp[3])
+    # Eq. 1 in float32, the expression core.power.power() evaluates;
+    # the (p_max - p_idle) delta is precomputed host-side in f64
+    # (powerp[4]) exactly as the eager path subtracts python floats
+    pw = eq1_power(mfu, powerp[0], powerp[4], powerp[2], powerp[3])
     pw64 = jnp.where(live, pw.astype(jnp.float64), 0.0)
 
     e_sum = jnp.sum(pw64 * dur_s)                 # W*s

@@ -17,8 +17,8 @@ request at a time, strictly serialized: request ``i`` goes to replica
 ``i % R`` (round-robin), its replica fast-forwards to the ready time,
 and its schedule is exactly one whole-prompt prefill followed by
 ``decode_tokens`` single-token decode stages at contexts ``L..L+D-1``.
-``replay_result`` reconstructs that schedule directly — aggregates via
-the same float expressions as ``stage_cost_scalar``, costs via the
+``replay_result`` reconstructs that schedule directly — aggregates from
+the same composition terms as ``stage_cost_scalar``, costs via the
 batched roofline (bit-identical to the scalar path by construction),
 and clocks via the same left-fold accumulation ``drive`` performs — so
 the replayed ``SimResult`` is **bit-equal** to what ``run_simulation``
@@ -84,18 +84,9 @@ def _service_bound(cfg: SimConfig, L: np.ndarray, D: np.ndarray
     em = cached_execution_model(cfg.model, cfg.device, cfg.tp, cfg.pp,
                                 cfg.execmodel)
     n = len(L)
-    kvpt = em.kv_bytes_per_token
-    w = em.sliding_window
-    avg_ctx = np.maximum(np.floor(L / 2.0), 1.0)
-    pre = StageBatch(
-        prefill_tokens=L, decode_count=np.zeros(n),
-        score_flops=L * em._score_per_token(avg_ctx),
-        kv_rw_bytes=L * kvpt)
-    ub_ctx = L + D                      # one past the last decode context
-    dec = StageBatch(
-        prefill_tokens=np.zeros(n), decode_count=np.ones(n),
-        score_flops=em._score_per_token(ub_ctx),
-        kv_rw_bytes=np.minimum(ub_ctx, w) * kvpt + kvpt)
+    pre = StageBatch(L, np.zeros(n), *em.prefill_terms(L, 0.0))
+    # one past the last decode context
+    dec = StageBatch(np.zeros(n), np.ones(n), *em.decode_terms(L + D))
     t = em.stage_cost_batch(StageBatch.concat([pre, dec])).t_total
     return t[:n] + (D + 1.0) * t[n:]
 
@@ -171,19 +162,15 @@ def replay_result(cfg: SimConfig) -> SimResult:
     is_pre = pos == 0
     ctx = Lf[req_idx] + (pos - 1)                 # decode ctx: L..L+D-1
 
-    # aggregates via the same float expressions as stage_cost_scalar
-    # (single-element sums are exact, so the vectorized forms match
-    # the scalar path bitwise)
-    kvpt = em.kv_bytes_per_token
-    w = em.sliding_window
-    avg_ctx = np.maximum(0.0 + np.floor(Lf / 2.0), 1.0)
-    score_pre = Lf * em._score_per_token(avg_ctx)
+    # aggregates from the composition terms stage_cost_scalar sums
+    # (single-element sums are exact, and offset 0 adds exact zeros, so
+    # the vectorized forms match the scalar path bitwise)
+    score_pre, kv_pre = em.prefill_terms(Lf, 0.0)
+    score_dec, kv_dec = em.decode_terms(ctx)
     npt = np.where(is_pre, Lf[req_idx], 0.0)
     nd = np.where(is_pre, 0.0, 1.0)
-    score = np.where(is_pre, score_pre[req_idx],
-                     em._score_per_token(ctx))
-    kv = np.where(is_pre, Lf[req_idx] * kvpt,
-                  np.minimum(ctx, w) * kvpt + kvpt)
+    score = np.where(is_pre, score_pre[req_idx], score_dec)
+    kv = np.where(is_pre, kv_pre[req_idx], kv_dec)
     costs = em.stage_cost_batch(
         StageBatch(prefill_tokens=npt, decode_count=nd,
                    score_flops=score, kv_rw_bytes=kv))

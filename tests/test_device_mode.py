@@ -26,8 +26,8 @@ from repro.configs.paper_models import PAPER_MODELS
 from repro.core.power import DEVICES
 from repro.sim import (PAPER_DEFAULT, SchedulerConfig, SimConfig,
                        WorkloadConfig, run_simulation)
-from repro.sim.execmodel import (JAX_BACKEND_RTOL, ExecutionModel,
-                                 StageBatch)
+from repro.sim.execmodel import (PARAMS_FIELDS, ExecutionModel, StageBatch,
+                                 _Params, _roofline)
 from repro.sim.trace import StageTrace
 from repro.sweep import SCHEMA_VERSION, SWEEPS, SweepRunner
 from repro.sweep import divergence
@@ -261,29 +261,46 @@ def test_scenario_digests_match_reference_construction():
 
 
 # ---------------------------------------------------------------------------
-# jax roofline backend parity across every paper model
+# the device kernel's roofline form against numpy, on every paper model
 # ---------------------------------------------------------------------------
+
+def _kernel_roofline(pre, dec, score, kv, params):
+    """``_roofline`` as ``_group_kernel`` runs it: jax.numpy, with the
+    parameters a traced vector rebuilt into ``_Params``."""
+    import jax.numpy as jnp
+    p = _Params(*(params[i] for i in range(len(PARAMS_FIELDS))))
+    return _roofline(pre, dec, score, kv, p, jnp)
+
 
 @pytest.mark.parametrize("name", sorted(PAPER_MODELS))
 def test_jax_backend_parity_all_paper_models(name):
-    # measured worst-case rel err across all models/hardware is ~2e-7
-    # (f32 rounding); JAX_BACKEND_RTOL = 1e-5 keeps >50x margin
+    # the same f64 arithmetic on XLA:CPU and numpy: at most a few ulps
+    # apart (XLA may fuse ops), so 1e-12 still catches any change of
+    # expression
+    import jax
+
+    kernel = jax.jit(_kernel_roofline)
     for dev, tp, pp in (("a100", 1, 1), ("h100", 2, 2)):
         em = ExecutionModel(PAPER_MODELS[name], DEVICES[dev],
                             tp=tp, pp=pp)
-        batch = StageBatch.concat([
-            em.aggregate([512], [128, 4096]),
-            em.aggregate([], [64] * 32),
-            em.aggregate([128, 1], [], [0, 1024]),
-            em.aggregate([1], [1]),
-        ])
+        rows = [em.stage_cost_scalar(*comp)[1:] for comp in (
+            ([512], [128, 4096], None),
+            ([], [64] * 32, None),
+            ([128, 1], [], [0, 1024]),
+            ([1], [1], None),
+            ([], [], None))]                     # an empty (padded) stage
+        batch = StageBatch(*(np.array(col) for col in zip(*rows)))
         ref = em.stage_cost_batch(batch)
-        jx = em.stage_cost_batch(batch, backend="jax")
-        for f in ("t_total", "t_compute", "t_memory", "t_collective",
-                  "flops_mlp", "flops_attn", "mfu"):
+        with jax.enable_x64(True):
+            jx = kernel(batch.prefill_tokens, batch.decode_count,
+                        batch.score_flops, batch.kv_rw_bytes,
+                        em.params_vector())
+        for f, v in zip(("t_total", "t_compute", "t_memory",
+                         "t_collective", "flops_mlp", "flops_attn", "mfu"),
+                        jx):
             np.testing.assert_allclose(
-                np.asarray(getattr(jx, f)), np.asarray(getattr(ref, f)),
-                rtol=JAX_BACKEND_RTOL, err_msg=f"{name} {dev} {f}")
+                np.asarray(v), np.asarray(getattr(ref, f)),
+                rtol=1e-12, err_msg=f"{name} {dev} {f}")
 
 
 # --------------------------------------------------------------------------

@@ -81,8 +81,8 @@ def test_earlier_phase_wins_even_when_later_cell_diverges_more():
 def test_tolerance_ladder_is_tightest_first():
     ladder = tolerance_contracts()
     assert [name for name, _ in ladder] == \
-        ["host-bitwise", "DEVICE_MODE_RTOL", "JAX_BACKEND_RTOL",
-         "DAY_FLUID_RTOL", "regression"]
+        ["host-bitwise", "DEVICE_MODE_RTOL", "DAY_FLUID_RTOL",
+         "regression"]
     rtols = [r for _, r in ladder]
     assert rtols == sorted(rtols)
     assert rtols[0] == 0.0 and math.isinf(rtols[-1])
@@ -92,7 +92,7 @@ def test_classify_against_named_contracts():
     assert classify(0.0) == "host-bitwise"
     assert classify(1e-6) == "DEVICE_MODE_RTOL"
     assert classify(5e-6) == "DEVICE_MODE_RTOL"
-    assert classify(8e-6) == "JAX_BACKEND_RTOL"
+    assert classify(8e-6) == "DAY_FLUID_RTOL"
     assert classify(5e-3) == "DAY_FLUID_RTOL"
     assert classify(0.5) == "regression"
     assert classify(math.inf) == "regression"

@@ -88,8 +88,8 @@ def test_tp_reduces_stage_time():
     from repro.sim.execmodel import ExecutionModel
     m1 = ExecutionModel(LLAMA3_8B, DEVICES["a100"], tp=1)
     m2 = ExecutionModel(LLAMA3_8B, DEVICES["a100"], tp=2)
-    c1 = m1.stage_cost([2048], [])
-    c2 = m2.stage_cost([2048], [])
+    c1 = m1.stage_cost_scalar([2048], [])[0]
+    c2 = m2.stage_cost_scalar([2048], [])[0]
     assert c2.t_total < c1.t_total
     assert c2.t_collective > 0 and c1.t_collective == 0
 

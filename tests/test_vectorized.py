@@ -8,16 +8,20 @@ loop mode on every pinned benchmark grid (fig1/fig3/exp5 single-site,
 exp6 fleet, exp7 shift), and (c) the stacked energy/carbon passes
 equal their per-scenario counterparts exactly.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 from _hypothesis_support import given, settings, st
 
 from repro.configs.paper_models import CODELLAMA_34B, LLAMA3_8B
 from repro.core.energy import operational_energy, stacked_energy_reports
-from repro.core.power import DEVICES, PowerModel
+from repro.core.power import DEVICES, PowerModel, eq1_power, power
 from repro.sim import (PAPER_DEFAULT, SchedulerConfig, SimConfig,
-                       StageBatch, WorkloadConfig, run_simulation)
+                       StageBatch, StageCost, WorkloadConfig,
+                       run_simulation)
 from repro.sim.execmodel import ExecutionModel, cached_execution_model
+from repro.sweep.device import _group_kernel
 from repro.sweep import SWEEPS, GridSpec, SweepRunner
 from repro.sweep.vectorized import group_by_trace
 
@@ -128,7 +132,7 @@ def test_fleet_site_trace_replays():
 
 
 # ---------------------------------------------------------------------------
-# scalar stage_cost == batched kernel rows (property test)
+# scalar stage_cost_scalar == batched kernel rows (property test)
 # ---------------------------------------------------------------------------
 
 _COMPOSITION = st.tuples(
@@ -142,29 +146,77 @@ _COMPOSITION = st.tuples(
        st.sampled_from([(1, 1), (2, 1), (1, 2), (2, 2)]))
 @settings(max_examples=25, deadline=None)
 def test_batch_rows_match_scalar_path(comps, model_name, tp_pp):
+    """The contract replay depends on: the event loop's scalar path and
+    the batched roofline over its logged aggregates agree bitwise."""
     model = {"llama3-8b": LLAMA3_8B, "codellama-34b": CODELLAMA_34B}[model_name]
     tp, pp = tp_pp
     em = ExecutionModel(model, DEVICES["a100"], tp=tp, pp=pp)
-    aggs, costs = [], []
+    costs, aggs = [], []
     for (pre, ctxs) in comps:
-        plens = [p for p, _ in pre]
-        offs = [o for _, o in pre]
-        aggs.append(em.aggregate(plens, ctxs, offs))
-        costs.append(em.stage_cost(plens, ctxs, offs))
-    cb = em.stage_cost_batch(StageBatch.concat(aggs))
-    for i, c in enumerate(costs):
-        assert cb.row(i) == c
+        cost, *agg = em.stage_cost_scalar([p for p, _ in pre], ctxs,
+                                          [o for _, o in pre])
+        costs.append(cost)
+        aggs.append(agg)
+    batch = StageBatch(*(np.array(col) for col in zip(*aggs)))
+    cb = em.stage_cost_batch(batch)
+    for i, (cost, agg) in enumerate(zip(costs, aggs)):
+        assert agg == [float(getattr(batch, f.name)[i])
+                       for f in dataclasses.fields(StageBatch)]
+        assert cost == StageCost(*(float(getattr(cb, f.name)[i])
+                                   for f in dataclasses.fields(StageCost)))
 
 
 def test_jax_backend_matches_numpy_closely():
-    em = ExecutionModel(LLAMA3_8B, DEVICES["a100"])
-    batch = StageBatch.concat([em.aggregate([512], [128, 4096]),
-                               em.aggregate([], [64] * 32),
-                               em.aggregate([128, 128], [], [0, 1024])])
-    ref = em.stage_cost_batch(batch)
-    jx = em.stage_cost_batch(batch, backend="jax")
-    np.testing.assert_allclose(jx.t_total, ref.t_total, rtol=1e-4)
-    np.testing.assert_allclose(jx.mfu, ref.mfu, rtol=1e-4)
+    """Eq. 1 has one expression. Given the device kernel's float32
+    constants it equals the host's ``power`` bitwise for every device
+    profile; jitted, as the kernel runs it, XLA:CPU fuses
+    ``p_idle + p_delta * x**gamma`` into one multiply-add, which stays
+    within one float32 ulp. The kernel's ``peak`` is exactly the largest
+    jitted ``eq1_power`` of the live stages' MFU."""
+    import jax
+
+    mfu = np.concatenate([np.linspace(0.0, 0.8, 401),
+                          [-0.1, 0.45, 1.0, 1e-9]])
+    eq1 = jax.jit(eq1_power)
+
+    def consts(dev):
+        return np.asarray([dev.p_idle, dev.p_max_inst, dev.mfu_sat,
+                           dev.gamma, dev.p_max_inst - dev.p_idle],
+                          np.float32)
+
+    for name, dev in sorted(DEVICES.items()):
+        c = consts(dev)
+        ref = np.asarray(power(mfu, dev))
+        with jax.enable_x64(True):
+            eager = np.asarray(eq1_power(mfu, c[0], c[4], c[2], c[3]))
+            jitted = np.asarray(eq1(mfu, c[0], c[4], c[2], c[3]))
+        assert np.array_equal(eager, ref), name
+        np.testing.assert_array_max_ulp(jitted, ref, maxulp=1)
+
+    cfg = SimConfig(model=LLAMA3_8B,
+                    workload=WorkloadConfig(n_requests=12, qps=4.0,
+                                            min_len=64, max_len=512,
+                                            seed=0),
+                    scheduler=SchedulerConfig(batch_cap=8,
+                                              chunk_prefill=256))
+    res = run_simulation(cfg)
+    em = cached_execution_model(cfg.model, cfg.device, cfg.tp, cfg.pp,
+                                cfg.execmodel)
+    c = consts(DEVICES[cfg.device])
+    tr = res.stages
+    pad = np.zeros(8)                       # padded rows, as the grid pads
+    comp = [np.concatenate([np.asarray(v, np.float64), pad])
+            for v in (tr.n_prefill_tokens, tr.n_decode_tokens,
+                      tr.score_flops, tr.kv_rw_bytes)]
+    with jax.enable_x64(True):
+        out = jax.jit(_group_kernel)(*comp, em.params_vector(), c, 1.0,
+                                     0.0, np.ones(1), np.zeros(1))
+        live_max = np.max(np.asarray(eq1(tr.mfu, c[0], c[4], c[2], c[3])))
+    peak = np.float32(out[3])
+    assert peak == live_max
+    np.testing.assert_array_max_ulp(
+        peak, np.max(np.asarray(power(tr.mfu, DEVICES[cfg.device]))),
+        maxulp=1)
 
 
 # ---------------------------------------------------------------------------
@@ -189,19 +241,16 @@ def test_stacked_energy_reports_bit_identical():
 
 def test_chunk_offset_adds_kv_read_and_score_context():
     em = ExecutionModel(LLAMA3_8B, DEVICES["a100"])
-    fresh = em.aggregate([256], [], [0])
-    cont = em.aggregate([256], [], [2048])
+    fresh, _, _, fresh_score, fresh_kv = em.stage_cost_scalar([256], [], [0])
+    cont, _, _, cont_score, cont_kv = em.stage_cost_scalar([256], [], [2048])
     kvpt = LLAMA3_8B.kv_bytes_per_token()
     # the continuation re-reads exactly the prior context's KV
-    assert cont.kv_rw_bytes[0] - fresh.kv_rw_bytes[0] == \
-        pytest.approx(2048 * kvpt)
+    assert cont_kv - fresh_kv == pytest.approx(2048 * kvpt)
     # and its score FLOPs see the offset context
-    assert cont.score_flops[0] > fresh.score_flops[0]
+    assert cont_score > fresh_score
     # continuation chunks therefore cost more wall-clock than a fresh
     # chunk of the same size (the under-counting the fix removes)
-    t_fresh = em.stage_cost([256], [], [0]).t_total
-    t_cont = em.stage_cost([256], [], [2048]).t_total
-    assert t_cont > t_fresh
+    assert cont.t_total > fresh.t_total
 
 
 def test_chunked_prefill_conserves_score_flops():
@@ -210,10 +259,9 @@ def test_chunked_prefill_conserves_score_flops():
     where the old accounting under-counted by ~2x at 4 chunks."""
     em = ExecutionModel(LLAMA3_8B, DEVICES["a100"])
     L, C = 4096, 512
-    whole = em.aggregate([L], []).score_flops[0]
-    chunked = sum(
-        em.aggregate([C], [], [off]).score_flops[0]
-        for off in range(0, L, C))
+    whole = em.stage_cost_scalar([L], [])[3]
+    chunked = sum(em.stage_cost_scalar([C], [], [off])[3]
+                  for off in range(0, L, C))
     assert chunked == pytest.approx(whole, rel=0.01)
 
 
@@ -242,7 +290,7 @@ def test_execution_model_caches_invariants():
     assert em.fpt_mlp == LLAMA3_8B.flops_per_token_mlp_total()
     # linearized score model reproduces the config method exactly
     for ctx in (1, 17, 1024, 100_000):
-        assert em._score_per_token(ctx) == \
+        assert em.decode_terms(ctx)[0] == \
             LLAMA3_8B.flops_attn_score_per_token(ctx)
     # the process-level constructor cache returns shared instances
     a = cached_execution_model(LLAMA3_8B, "a100", 1, 1, em.cfg)
