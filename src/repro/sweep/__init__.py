@@ -5,7 +5,7 @@ the paper's seven experiments as predefined sweeps (``repro.sweep.cli``).
 from repro.sweep.cache import ResultCache, default_cache_root
 from repro.sweep.grid import (DEFAULT_GRID_CI, SCHEMA_VERSION, GridSpec,
                               Scenario, config_digest, derive_seed,
-                              model_registry, with_overrides)
+                              fill_digests, model_registry, with_overrides)
 from repro.sweep.report import (flatten, format_rows, format_table, to_csv,
                                 to_json, write_outputs)
 from repro.sweep.remote import (RemoteCoordinator, RemoteOptions,
@@ -21,7 +21,8 @@ from repro.sweep.vectorized import (estimate_group_cost,
 __all__ = [
     "ResultCache", "default_cache_root",
     "DEFAULT_GRID_CI", "SCHEMA_VERSION", "GridSpec", "Scenario",
-    "config_digest", "derive_seed", "model_registry", "with_overrides",
+    "config_digest", "derive_seed", "fill_digests", "model_registry",
+    "with_overrides",
     "flatten", "format_rows", "format_table", "to_csv", "to_json",
     "write_outputs",
     "RemoteCoordinator", "RemoteOptions", "RemoteStats", "pack_shards",

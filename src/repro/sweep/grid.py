@@ -123,6 +123,18 @@ def _jsonable(value):
     return value
 
 
+def _override_token(value):
+    """A hashable token that two override values share only if they
+    serialise alike: scalars by type and ``repr`` (1, 1.0, True and
+    -0.0, 0.0 stay apart), tuples element by element, anything else by
+    identity (the grid spec keeps it alive)."""
+    if isinstance(value, tuple):
+        return tuple, tuple(_override_token(v) for v in value)
+    if value is None or isinstance(value, (str, int, float)):
+        return type(value), repr(value)
+    return object, id(value)
+
+
 def _canonical_json(obj) -> str:
     return json.dumps(obj, sort_keys=True, default=str,
                       separators=(",", ":"))
@@ -135,12 +147,26 @@ def config_blob(cfg) -> str:
     return _canonical_json(dataclasses.asdict(cfg))
 
 
+# a scenario digest is the sha256 of
+#   {"cfg":<config blob>,"extra":<knob JSON>,"schema":<version>}
+# -- the exact bytes json.dumps(payload, sort_keys=True) would produce,
+# since the payload keys already sort cfg < extra < schema. It is taken
+# as a prefix, hashed once per config, and a per-scenario suffix.
+_DIGEST_SUFFIX = f',"schema":{SCHEMA_VERSION}}}'
+
+
+def _digest_prefix(cfg_json: str):
+    return hashlib.sha256(f'{{"cfg":{cfg_json},"extra":'.encode())
+
+
+def _digest_with(prefix, extra_json: str) -> str:
+    h = prefix.copy()
+    h.update((extra_json + _DIGEST_SUFFIX).encode())
+    return h.hexdigest()
+
+
 def _digest_from_blobs(cfg_json: str, extra_json: str) -> str:
-    # assembles the exact bytes json.dumps(payload, sort_keys=True)
-    # would produce: the payload keys already sort cfg < extra < schema
-    blob = (f'{{"cfg":{cfg_json},"extra":{extra_json},'
-            f'"schema":{SCHEMA_VERSION}}}')
-    return hashlib.sha256(blob.encode()).hexdigest()
+    return _digest_with(_digest_prefix(cfg_json), extra_json)
 
 
 def config_digest(cfg: SimConfig, extra: Optional[Mapping] = None) -> str:
@@ -179,7 +205,8 @@ class Scenario:
     # grouping and record assembly all consult them, and one sha256
     # over the full config tree per consult would dominate the
     # per-scenario cost on large vectorized grids (scenarios are
-    # treated as immutable once expanded)
+    # treated as immutable once expanded). A runner fills them for a
+    # whole sweep at once with ``fill_digests``.
     _key: Optional[str] = dataclasses.field(
         default=None, init=False, repr=False, compare=False)
     _trace_key: Optional[str] = dataclasses.field(
@@ -189,20 +216,26 @@ class Scenario:
 
     @property
     def cfg_blob(self) -> str:
-        """Canonical config JSON, serialized once per scenario — both
+        """Canonical config JSON, serialized at most once per scenario
+        (and once per shared config object by ``fill_digests``) — both
         digests below reuse it (the asdict+encode pass dominates
         per-scenario runner overhead on large stacked grids)."""
         if self._cfg_blob is None:
             self._cfg_blob = config_blob(self.cfg)
         return self._cfg_blob
 
+    def _knob_json(self) -> str:
+        """Canonical JSON of the report knobs: the ``extra`` of
+        ``key``'s digest."""
+        return _canonical_json({
+            "pue": self.pue, "grid_ci": self.grid_ci,
+            "post": self.post, "post_params": self.post_params,
+        })
+
     @property
     def key(self) -> str:
         if self._key is None:
-            self._key = _digest_from_blobs(self.cfg_blob, _canonical_json({
-                "pue": self.pue, "grid_ci": self.grid_ci,
-                "post": self.post, "post_params": self.post_params,
-            }))
+            self._key = _digest_from_blobs(self.cfg_blob, self._knob_json())
         return self._key
 
     @property
@@ -213,6 +246,38 @@ class Scenario:
         if self._trace_key is None:
             self._trace_key = _digest_from_blobs(self.cfg_blob, "{}")
         return self._trace_key
+
+
+def fill_digests(scenarios: Sequence[Scenario]) -> int:
+    """Compute the key and trace key of every scenario whose key is not
+    cached yet, serialising each distinct config *object* once: the
+    scenarios that hold one share its blob, its sha256 prefix and its
+    trace key, and each hashes only its own report knobs. Keys are
+    byte-for-byte those of the lazy properties. Returns the number of
+    config trees serialised.
+
+    The memo is keyed by ``id(cfg)`` and lives only for this call,
+    while ``scenarios`` keeps every config alive, so an id is never
+    reused within it (configs are mutable dataclasses and are not
+    hashed by value)."""
+    memo: Dict[int, tuple] = {}
+    serialised = 0
+    for sc in scenarios:
+        if sc._key is not None:
+            continue
+        shared = memo.get(id(sc.cfg))
+        if shared is None:
+            if sc._cfg_blob is None:
+                sc._cfg_blob = config_blob(sc.cfg)
+                serialised += 1
+            prefix = _digest_prefix(sc._cfg_blob)
+            shared = memo[id(sc.cfg)] = (sc._cfg_blob, prefix,
+                                         _digest_with(prefix, "{}"))
+        sc._cfg_blob, prefix, trace_key = shared
+        sc._key = _digest_with(prefix, sc._knob_json())
+        if sc._trace_key is None:
+            sc._trace_key = trace_key
+    return serialised
 
 
 @dataclasses.dataclass
@@ -239,6 +304,10 @@ class GridSpec:
         keys = list(self.axes.keys())
         value_lists = [self.axes[k] for k in keys]
         scenarios: List[Scenario] = []
+        # combinations that differ only in report-only axes resolve to
+        # equal overrides and share one config object, so a sweep
+        # serialises its tree once (``fill_digests``)
+        cfgs: Dict[tuple, object] = {}
         for combo in itertools.product(*value_lists):
             overrides: Dict[str, object] = dict(self.fixed)
             params: Dict[str, object] = {}
@@ -288,7 +357,12 @@ class GridSpec:
                             "site ci_trace instead")
                 else:
                     cfg_overrides[path] = value
-            cfg = with_overrides(self.base, cfg_overrides)
+            share = tuple((path, _override_token(value))
+                          for path, value in cfg_overrides.items())
+            cfg = cfgs.get(share)
+            if cfg is None:
+                cfg = cfgs[share] = with_overrides(self.base,
+                                                   cfg_overrides)
             label = ",".join(f"{k}={params[k]}" for k in params) or "base"
             scenarios.append(Scenario(
                 cfg=cfg, params=params, tag=f"{self.tag}/{label}",

@@ -41,7 +41,7 @@ from repro.core.power import DEVICES
 from repro.fleet.config import FleetConfig
 from repro.obs.spans import PROFILER
 from repro.sweep.cache import ResultCache
-from repro.sweep.grid import SCHEMA_VERSION, Scenario
+from repro.sweep.grid import SCHEMA_VERSION, Scenario, fill_digests
 
 EXECUTION_MODES = ("vectorized", "event_loop", "device")
 #: where cache-missed scenarios execute: in this process (pool) or on
@@ -268,6 +268,7 @@ class SweepStats:
     workers: int = 1
     mode: str = "vectorized"
     trace_groups: int = 0     # unique simulation traces actually driven
+    config_blobs: int = 0     # config trees serialised for the keys
     event_loops: int = 0      # device mode: groups run through the loop
     replayed: int = 0         # device mode: groups shared via divergence
     loop_iterations: int = 0  # device mode: iterations of those loops
@@ -321,7 +322,8 @@ class SweepStats:
         return (f"{self.total} scenarios: {self.executed} executed, "
                 f"{self.cache_hits} cache hits, "
                 f"{self.elapsed_s:.2f}s wall, {self.workers} worker(s)"
-                f"{groups}{shared}{eff}{rss}{rem}")
+                f"{groups}{shared}, keys: {self.config_blobs} config(s) "
+                f"for {self.total} scenario(s){eff}{rss}{rem}")
 
 
 def _peak_rss_mb() -> float:
@@ -434,6 +436,7 @@ class SweepRunner:
         dup_of: Dict[str, List[int]] = {}   # key -> later same-key idxs
         with PROFILER.span("cache.lookup"):
             with PROFILER.span("sweep.key_digest"):
+                stats.config_blobs = fill_digests(scenarios)
                 keys = [sc.key for sc in scenarios]
             for i, (sc, key) in enumerate(zip(scenarios, keys)):
                 hit = (self.cache.get(key)
